@@ -12,6 +12,7 @@ from __future__ import annotations
 import jax
 
 from repro.distributed.sharding import default_rules, shardings_for
+from repro.launch.mesh import make_host_mesh
 
 
 def degraded_mesh(devices=None, model: int | None = None):
@@ -24,11 +25,7 @@ def degraded_mesh(devices=None, model: int | None = None):
             if n % m == 0 and m <= n:
                 model = m
                 break
-    data = n // model
-    import numpy as np
-    arr = np.array(devices[: data * model]).reshape(data, model)
-    from jax.sharding import Mesh
-    return Mesh(arr, ("data", "model"))
+    return make_host_mesh(model, devices)
 
 
 def reshard_state(state, axes_tree, new_mesh, sequence_parallel: bool = False):

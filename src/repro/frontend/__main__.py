@@ -1,8 +1,11 @@
-"""Frontend smoke CLI: lift one traced workload and simulate it on CPU.
+"""Frontend smoke CLI: lift one traced workload and simulate it on the host.
 
 Used by CI (and humans) to prove the real-kernel path end to end::
 
-    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.frontend traced_matmul
+    PYTHONPATH=src python -m repro.frontend traced_matmul
+
+Tracing runs on JAX's default backend; set ``JAX_PLATFORMS`` in the shell to
+choose another.
 
 Lifts the named workload, checks the interval plan validates, runs it on
 both simulator engines across a design, and fails loudly on any divergence.
@@ -11,15 +14,10 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 
 
 def main(argv=None) -> int:
-    # Tracing probes jax backends: pin the CPU platform up front so a host
-    # with a TPU-less libtpu never hangs (same class as test_pipeline_parallel).
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
     from repro.frontend.workloads import (DEFAULT_MAXREGCOUNT, TRACED_NAMES,
                                           build_traced_workload)
 

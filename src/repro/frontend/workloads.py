@@ -132,13 +132,6 @@ def build_traced_workload(name: str,
     spec = TRACED_SPECS[name]
 
     def build() -> "Workload":
-        import os
-
-        # Tracing probes jax backends: pin the CPU platform before the first
-        # jax import so hosts with a TPU-less libtpu never hang, whichever
-        # entry point (bench_sim/run.py/pool worker/CLI) triggered the lift.
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
         from repro.workloads.suite import Workload
 
         from .jaxpr_lift import lift_fn

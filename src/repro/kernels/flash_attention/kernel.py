@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -94,7 +92,7 @@ def flash_attention_kernel(
             pltpu.VMEM((bq,), jnp.float32),      # running sum
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

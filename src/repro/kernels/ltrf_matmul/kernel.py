@@ -23,7 +23,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
+# Scoped VMEM the kernel requests (``vmem_limit_bytes``): twice the 16 MiB
+# default, a quarter of a v5e core's 128 MiB.  `ops.pick_blocks` keeps the
+# blocks' allocation under it.
+VMEM_LIMIT = 32 * 2 ** 20
 
 
 def _ltrf_matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
@@ -74,8 +77,9 @@ def ltrf_matmul_kernel(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
     )(x, w)

@@ -1,9 +1,10 @@
 """Jit wrapper: LTRF-planned matmul with interval-derived tile sizes.
 
-`ltrf_matmul(x, w)` consults `repro.core.plan.plan_for_matmul` to choose
-(bk, bn) so the in-flight working set — two weight-tile slots (double
-buffer), the x tile and the fp32 accumulator — fits the VMEM budget, then
-pads to MXU-aligned blocks and calls the Pallas kernel.
+`ltrf_matmul(x, w)` chooses (bm, bk, bn) so the VMEM that Pallas really
+allocates — double-buffered x, w and out tiles plus the fp32 accumulator —
+fits the scoped-VMEM limit the kernel requests, then pads to MXU-aligned
+blocks and calls the Pallas kernel.  `matmul_plan` gives the matching
+`repro.core.plan.plan_for_matmul` interval plan for the weight stream.
 """
 from __future__ import annotations
 
@@ -14,29 +15,30 @@ import jax.numpy as jnp
 
 from repro.core.plan import plan_for_matmul
 
-from .kernel import ltrf_matmul_kernel
+from .kernel import VMEM_LIMIT, ltrf_matmul_kernel
 from .ref import matmul_ref
-
-VMEM_BUDGET = 96 * 2 ** 20  # leave headroom below the ~128MB v5e VMEM
 
 
 def _round_up(v: int, m: int) -> int:
     return -(-v // m) * m
 
 
-def pick_blocks(M: int, K: int, N: int, dtype_bytes: int = 2,
-                vmem_budget: int = VMEM_BUDGET) -> tuple[int, int, int]:
-    """Choose MXU-aligned (bm, bk, bn) whose working set fits VMEM.
+def vmem_bytes(bm: int, bk: int, bn: int, dtype_bytes: int) -> int:
+    """VMEM the kernel allocates: Pallas double-buffers every blocked
+    operand (x, w and out tiles), plus the single fp32 accumulator."""
+    return (2 * (bm * bk + bk * bn + bm * bn) * dtype_bytes
+            + bm * bn * 4)
 
-    working set = bm*bk (x tile) + 2*bk*bn (double-buffered weight tiles)
-                + bm*bn*4 (fp32 acc) + bm*bn (out tile)."""
+
+def pick_blocks(M: int, K: int, N: int, dtype_bytes: int = 2,
+                vmem_limit: int = VMEM_LIMIT) -> tuple[int, int, int]:
+    """Choose MXU-aligned (bm, bk, bn), largest weight tile first, whose
+    `vmem_bytes` fits ``vmem_limit``."""
     bm = min(_round_up(min(M, 256), 128), _round_up(M, 128))
     best = None
     for bk in (2048, 1024, 512, 256, 128):
         for bn in (1024, 512, 256, 128):
-            ws = (bm * bk * dtype_bytes + 2 * bk * bn * dtype_bytes
-                  + bm * bn * 4 + bm * bn * dtype_bytes)
-            if ws <= vmem_budget:
+            if vmem_bytes(bm, bk, bn, dtype_bytes) <= vmem_limit:
                 cand = (bk * bn, bk, bn)
                 if best is None or cand > best:
                     best = cand
@@ -61,7 +63,7 @@ def ltrf_matmul(x, w, bm: int = 0, bk: int = 0, bn: int = 0,
 
 
 def matmul_plan(M: int, K: int, N: int, dtype_bytes: int = 2,
-                vmem_budget: int = VMEM_BUDGET):
+                vmem_budget: int = VMEM_LIMIT):
     """The explicit IntervalPlan for this matmul's weight stream (for
     inspection/validation: one prefetch round per interval, slots
     conflict-free)."""
@@ -72,4 +74,5 @@ def matmul_plan(M: int, K: int, N: int, dtype_bytes: int = 2,
     return plan, (bm, bk, bn)
 
 
-__all__ = ["ltrf_matmul", "matmul_plan", "matmul_ref", "pick_blocks"]
+__all__ = ["ltrf_matmul", "matmul_plan", "matmul_ref", "pick_blocks",
+           "vmem_bytes"]

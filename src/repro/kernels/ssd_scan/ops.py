@@ -29,8 +29,13 @@ def ssd_scan(x, dt, A, Bm, Cm, chunk: int = 64, interpret: bool = False):
     Sp = S + pad
     nc = Sp // Q
 
-    y_intra, states, in_decay, chunk_decay = ssd_chunk_kernel(
-        x, dt, A, Bm, Cm, chunk=Q, interpret=interpret)
+    dA = dt.astype(jnp.float32) * A.astype(jnp.float32)[None, None, :]
+    cum = jnp.cumsum(dA.reshape(Bsz, nc, Q, H), axis=2)          # (B,nc,Q,H)
+    y_intra, states = ssd_chunk_kernel(
+        x, dt, cum.reshape(Bsz, Sp, H), Bm, Cm, chunk=Q, interpret=interpret)
+    cum = cum.transpose(0, 1, 3, 2)                              # (B,nc,H,Q)
+    in_decay = jnp.exp(jnp.clip(cum, -60.0, 0.0))
+    chunk_decay = in_decay[..., -1:]                             # (B,nc,H,1)
 
     # inter-chunk recurrence over (B,H,P,N) chunk states
     def step(h_prev, inp):

@@ -16,6 +16,7 @@ import time
 import numpy as np
 
 from repro.configs import get_arch, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ServeConfig, ServingEngine
 
 
@@ -53,6 +54,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     stats = serve(args.arch, smoke=not args.full, n_requests=args.requests)
     print(", ".join(f"{k}={v if not isinstance(v, float) else round(v, 2)}"
                     for k, v in stats.items()))

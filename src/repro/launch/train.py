@@ -11,13 +11,15 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
+import tempfile
 import time
 
 import jax
-import numpy as np
 
 from repro.checkpoint import Checkpointer
 from repro.configs import get_arch, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs.base import ShapeConfig
 from repro.data import DataConfig, PrefetchingLoader
 from repro.distributed.fault import FaultConfig, FaultTolerantTrainer
@@ -26,7 +28,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.optim.compression import CompressionConfig
 from repro.runtime.train_step import (
-    batch_axes_for, build_train_step, make_train_state,
+    batch_axes_for, batch_shardings, build_train_step, make_train_state,
 )
 
 log = logging.getLogger("repro.train")
@@ -36,10 +38,14 @@ def train(arch_id: str, smoke: bool = True, steps: int = 50,
           batch: int = 8, seq: int = 64, ckpt_dir: str | None = None,
           ckpt_every: int = 20, compress: bool = False,
           inject_failures: dict[int, int] | None = None,
-          n_micro: int = 1, seed: int = 0):
+          n_micro: int = 1, seed: int = 0, mesh=None):
+    """Train ``steps`` steps on ``mesh`` (default: every local device).
+
+    State is placed by the logical-axis rules, and every batch is put on
+    the mesh (batch dimension over ``data``) before the step sees it."""
     cfg = get_smoke(arch_id) if smoke else get_arch(arch_id)
     shape = ShapeConfig("driver", seq, batch, "train")
-    mesh = make_host_mesh()
+    mesh = mesh if mesh is not None else make_host_mesh()
     rules = default_rules(mesh)
 
     state, state_axes = make_train_state(cfg, jax.random.PRNGKey(seed))
@@ -49,12 +55,18 @@ def train(arch_id: str, smoke: bool = True, steps: int = 50,
 
     opt_cfg = AdamWConfig(lr=1e-3, warmup_steps=10, total_steps=max(steps, 1))
     comp = CompressionConfig(enabled=True) if compress else None
-    step_fn = jax.jit(
+    b_sh = batch_shardings(rules, batch_axes_for(cfg, "train"))
+    jitted = jax.jit(
         build_train_step(cfg, rules, opt_cfg, comp, n_micro=n_micro),
         donate_argnums=(0,))
 
+    def step_fn(state, batch):
+        return jitted(state, jax.device_put(batch, b_sh))
+
     loader = PrefetchingLoader(cfg, shape, DataConfig(seed=seed + 1))
-    ckpt = Checkpointer(ckpt_dir or f"/tmp/repro_ckpt_{arch_id}", keep=2)
+    ckpt = Checkpointer(
+        ckpt_dir or os.path.join(tempfile.gettempdir(), f"repro_ckpt_{arch_id}"),
+        keep=2)
     trainer = FaultTolerantTrainer(
         step_fn=step_fn, checkpointer=ckpt, loader=loader,
         cfg=FaultConfig(ckpt_every=ckpt_every,
@@ -71,6 +83,7 @@ def train(arch_id: str, smoke: bool = True, steps: int = 50,
         "straggler_fallbacks": loader.straggler_fallbacks,
         "wall_s": dt,
         "state": state,
+        "batch_shardings": b_sh,
     }
 
 
@@ -85,6 +98,7 @@ def main() -> None:
     ap.add_argument("--n-micro", type=int, default=1)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     out = train(args.arch, smoke=not args.full, steps=args.steps,
                 batch=args.batch, seq=args.seq, compress=args.compress,
                 n_micro=args.n_micro)

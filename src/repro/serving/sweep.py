@@ -42,6 +42,7 @@ from __future__ import annotations
 import heapq
 import json
 import hashlib
+import multiprocessing
 import os
 import pathlib
 import time
@@ -63,7 +64,7 @@ from repro.sim.analytic import (ANALYTIC_REV, CALIB_REV, DEFAULT_CALIBRATION,
                                 load_calibration, pareto_frontier)
 from repro.sim.engine import ENGINE_REV
 from repro.sim.gpu import GpuResult, aggregate, per_sm_configs
-from repro.workloads import get_workload
+from repro.workloads import Workload, get_workload
 
 ROOT = pathlib.Path(__file__).resolve().parents[3]
 SIMCACHE = pathlib.Path(os.environ.get(
@@ -71,44 +72,30 @@ SIMCACHE = pathlib.Path(os.environ.get(
 
 Job = tuple[str, SimConfig]
 
-# In 'auto' batch mode the vectorized engine only engages once a prefill
-# has this many supported misses: below that, jit compilation costs more
-# than it saves and per-job latency histograms lose their meaning.
-# Explicit opt-in (batch=True or REPRO_SIM_BATCH=1) batches everything it
-# can.  On parallel backends (GPU/TPU) the bar is low; on CPU the BATCH_REV
-# 2 fused tick beats the event-heap engine in *steady state* (measured:
-# `batch_engine` in BENCH_sim.json), but a cold prefill still pays tens of
-# seconds of XLA compilation per shape bucket, so the CPU bar is set where
-# a tracked-sweep-sized prefill amortizes it and a smoke-sized one never
-# triggers it.
+# In 'auto' batch mode the vectorized engine engages only on a parallel
+# backend (GPU/TPU) and only once a prefill has this many supported misses:
+# below that, jit compilation costs more than it saves and per-job latency
+# histograms lose their meaning.  On XLA:CPU the event-heap engine is the
+# faster one: the tracked 196-job sweep took 427 s through the batch engine
+# and 112 s through the event heap (one process each, same 8-core host),
+# so 'auto' leaves CPU hosts on the classic path.  Explicit opt-in
+# (batch=True or REPRO_SIM_BATCH=1) batches everything it can, anywhere.
 _MIN_AUTO_BATCH = 8
-_MIN_AUTO_BATCH_CPU = 64
 
 
-def _auto_batch_threshold() -> int:
-    """Supported-miss count at which 'auto' mode engages the batch engine.
+def _auto_batch_threshold() -> int | None:
+    """Supported-miss count at which 'auto' mode engages the batch engine,
+    or None where it never does.
 
     Deliberately refuses to *import* jax for the probe: a cache lookup
-    should not cost a multi-second import.  If jax is already up on a
-    non-CPU backend the low bar applies; otherwise (plain CPU host, or jax
-    not loaded yet — `run_batch` imports it lazily only once the threshold
-    is actually met) the compile-amortizing CPU bar applies."""
+    should not cost a multi-second import.  Only a process whose jax is
+    already up on a non-CPU backend batches automatically."""
     import sys
 
     j = sys.modules.get("jax")
-    if j is not None:
-        try:
-            if j.devices()[0].platform != "cpu":
-                return _MIN_AUTO_BATCH
-        except Exception:  # noqa: BLE001 - any probe failure means "cpu"
-            pass
-    return _MIN_AUTO_BATCH_CPU
-
-
-def _auto_batch_ok() -> bool:
-    """Back-compat shim: 'auto' mode now always consults
-    `_auto_batch_threshold` (CPU hosts batch too, at a higher bar)."""
-    return True
+    if j is not None and j.devices()[0].platform != "cpu":
+        return _MIN_AUTO_BATCH
+    return None
 
 # Failure/retry classification (FailureRecord.kind):
 #   transient - the job raised an ordinary exception (incl. injected faults)
@@ -415,15 +402,23 @@ def _pid_alive(pid: int) -> bool:
 # --------------------------------------------------------------------------
 # Pool worker entry point (module-level: must pickle by reference)
 
-def _run_job(job: Job, watchdog_max_cycles: int = 0) -> tuple[str, SimConfig, dict]:
+_WORKER_START_S = 300.0
+
+
+def _run_job(job: Job, watchdog_max_cycles: int = 0,
+             workload: Workload | None = None) -> tuple[str, SimConfig, dict]:
     name, cfg = job
     faults.fault_point("run", job_label(job))
     run_cfg = cfg
     if watchdog_max_cycles and not cfg.max_cycles:
         run_cfg = replace(cfg, max_cycles=watchdog_max_cycles)
-    # get_workload resolves lazy suites (e.g. traced kernels) in pool workers
-    res = simulate(get_workload(name), run_cfg)
+    res = simulate(workload or get_workload(name), run_cfg)
     return name, cfg, asdict(res)
+
+
+def _worker_start(barrier) -> None:
+    """Pool initializer: a worker takes jobs once every worker has started."""
+    barrier.wait(timeout=_WORKER_START_S)
 
 
 # --------------------------------------------------------------------------
@@ -441,7 +436,13 @@ class _JobState:
 
 
 class _Dispatcher:
-    """Future-per-job process-pool dispatcher with retry/timeout/recycle."""
+    """Future-per-job process-pool dispatcher with retry/timeout/recycle.
+
+    Workers are started with ``spawn``, never forked: the parent may hold
+    the accelerator (the batch engine, a traced-kernel lift), and a forked
+    child would inherit that runtime.  The parent resolves every workload
+    before the pool starts and ships it with the job, so a worker only runs
+    the host-side event-heap engine and never initializes a jax backend."""
 
     def __init__(self, processes: int, sweep: SweepConfig, on_success,
                  metrics: MetricsRegistry | None = None) -> None:
@@ -451,6 +452,7 @@ class _Dispatcher:
         self.metrics = metrics or MetricsRegistry()
         self.pool: ProcessPoolExecutor | None = None
         self.pool_recycles = 0
+        self.workloads: dict[str, Workload] = {}
 
     # -- telemetry ---------------------------------------------------------
     def _mark_submit(self, st: _JobState) -> None:
@@ -463,8 +465,21 @@ class _Dispatcher:
     # -- pool lifecycle ----------------------------------------------------
     def _fresh_pool(self) -> ProcessPoolExecutor:
         if self.pool is None:
-            self.pool = ProcessPoolExecutor(max_workers=self.processes)
+            ctx = multiprocessing.get_context("spawn")
+            pool = ProcessPoolExecutor(
+                max_workers=self.processes, mp_context=ctx,
+                initializer=_worker_start,
+                initargs=(ctx.Barrier(self.processes),))
+            # a spawned worker pays for its imports at start-up: hold every
+            # job's timeout clock until all workers are up
+            wait([pool.submit(os.getpid) for _ in range(self.processes)])
+            self.pool = pool
         return self.pool
+
+    def _submit(self, st: _JobState) -> Future:
+        return self._fresh_pool().submit(
+            _run_job, st.job, self.cfg.watchdog_max_cycles,
+            self.workloads[st.job[0]])
 
     def _kill_pool(self) -> None:
         """Tear the pool down even if workers are hung or dead."""
@@ -534,17 +549,16 @@ class _Dispatcher:
         serially (one job in flight in a fresh pool) makes the next break
         unambiguous: only the actual crasher is charged a ``crash``
         attempt; innocent bystanders complete here for free."""
-        deadline = (time.monotonic() + self.cfg.job_timeout_s
-                    if self.cfg.job_timeout_s else None)
         try:
-            fut = self._fresh_pool().submit(
-                _run_job, st.job, self.cfg.watchdog_max_cycles)
+            fut = self._submit(st)
         except BrokenProcessPool:
             self._kill_pool()
             if self._charge(st, "crash", "pool broke on submit"):
                 self._requeue(st, ready, now_seq)
             return
         self._mark_submit(st)
+        deadline = (st.submitted_at + self.cfg.job_timeout_s
+                    if self.cfg.job_timeout_s else None)
         timeout = None if deadline is None else max(
             deadline - time.monotonic(), 0.0)
         done, _ = wait([fut], timeout=timeout)
@@ -573,6 +587,9 @@ class _Dispatcher:
 
     # -- main loop ---------------------------------------------------------
     def run(self, jobs: list[Job]) -> tuple[list[_JobState], int]:
+        # lift lazy suites (traced kernels trace through jax) here, not in
+        # the workers
+        self.workloads = {name: get_workload(name) for name, _ in jobs}
         t0 = time.monotonic()
         states = [_JobState(job=j, enqueued_at=t0) for j in jobs]
         seq_counter = iter(range(1, 1 << 30))
@@ -590,18 +607,17 @@ class _Dispatcher:
                 while ready and ready[0][0] <= now \
                         and len(inflight) < self.processes:
                     _, _, st = heapq.heappop(ready)
-                    deadline = (now + self.cfg.job_timeout_s
-                                if self.cfg.job_timeout_s else float("inf"))
                     try:
-                        fut = self._fresh_pool().submit(
-                            _run_job, st.job, self.cfg.watchdog_max_cycles)
+                        fut = self._submit(st)
                     except BrokenProcessPool:
                         self._kill_pool()
                         if self._charge(st, "crash", "pool broke on submit"):
                             self._requeue(st, ready, seq_counter)
                         continue
                     self._mark_submit(st)
-                    inflight[fut] = (st, deadline)
+                    inflight[fut] = (st, st.submitted_at + self.cfg.job_timeout_s
+                                     if self.cfg.job_timeout_s
+                                     else float("inf"))
                 if not inflight:
                     if ready:
                         time.sleep(max(ready[0][0] - time.monotonic(), 0.0))
@@ -940,11 +956,11 @@ class SimRunner:
         batch_states: list[_JobState] = []
         if misses:
             mode = self._batch_mode()
-            if mode in ("on", "auto"):
+            min_jobs = (1 if mode == "on" else
+                        _auto_batch_threshold() if mode == "auto" else None)
+            if min_jobs is not None:
                 misses, batch_states = self._prefill_batch(
-                    misses,
-                    min_jobs=(_auto_batch_threshold() if mode == "auto"
-                              else 1))
+                    misses, min_jobs=min_jobs)
             if misses:
                 if self.processes <= 1 or len(misses) == 1:
                     self._prefill_inline(misses, report)
@@ -1097,12 +1113,8 @@ class SimRunner:
         chaos harness targets the per-job classic paths (fault points,
         retries, pool recycles), which the vectorized engine bypasses.
 
-        'auto' engages the batch engine above a platform-dependent
-        supported-miss threshold (`_auto_batch_threshold`): a low bar on
-        parallel backends, a compile-amortizing bar on CPU — where the
-        BATCH_REV 2 fused tick beats the event-heap engine in steady state
-        (the measured `batch_engine` verdict in BENCH_sim.json) but cold
-        XLA compilation still costs tens of seconds per shape bucket."""
+        'auto' engages the batch engine above a supported-miss threshold
+        on a parallel backend and never on CPU (`_auto_batch_threshold`)."""
         if faults.active_plan() is not None:
             return "off"
         if self.batch is True:
@@ -1121,9 +1133,8 @@ class SimRunner:
         """Run the batch-supported misses through the vectorized engine.
 
         Returns (jobs left for the classic backends, completed job states).
-        Any whole-batch failure (jax unavailable, engine bug) degrades to
-        the classic path with every job intact — the batch engine is an
-        accelerator, never a new single point of failure."""
+        A failure of the batch engine itself (a device error, an engine
+        bug) propagates: rerunning the batch on the host would hide it."""
         from repro.sim.batch import batch_supported, run_batch
 
         supported = [j for j in misses if batch_supported(j[1])]
@@ -1138,10 +1149,7 @@ class SimRunner:
             if wd and not cfg.max_cycles:
                 run_cfg = replace(cfg, max_cycles=wd)
             run_jobs.append((get_workload(name), run_cfg))
-        try:
-            outcomes = run_batch(run_jobs)
-        except Exception:  # noqa: BLE001 - degrade to the classic backends
-            return misses, []
+        outcomes = run_batch(run_jobs)
         per_job = max(time.monotonic() - t0, 0.0) / len(supported)
         states: list[_JobState] = []
         for job, out in zip(supported, outcomes):

@@ -23,10 +23,15 @@ mode (those are compile-side: the batch engine consumes the same
 fall back to the scalar event engine, job by job.
 
 Numeric discipline: every float the scalar engines touch is a Python f64,
-so the batch engine runs under ``jax.experimental.enable_x64`` and performs
-the *identical* operations in the *identical* order (token-bucket refills,
-``int()`` truncations, DRAM jitter hashes) — IEEE f64 arithmetic is then
-bit-equal between the scalar and vector paths by construction.
+and the batch engine performs the *identical* operations in the *identical*
+order (token-bucket refills, ``int()`` truncations, DRAM jitter hashes).  It
+keeps each float as its IEEE-754 bit pattern in an int64 and adds, subtracts
+and truncates with exact integer arithmetic (`f64bits`), so the results are
+bit-equal to Python's on every backend — a TPU's emulated f64 is not.
+Products and quotients of configuration constants (refill amounts, jitter
+latencies, prefetch latencies, the L1-hit threshold) are tabulated on the
+host in numpy f64.  The loop holds no floating-point value at all; it runs
+under ``jax.enable_x64`` for its int64 state.
 
 Why lockstep is exact: the scalar tick's sequential sub-loops collapse.
 * The round-robin issue scan is rank arithmetic: the chosen warp is the
@@ -75,7 +80,6 @@ the scalar `_next_event` — with the skipped cycles charged to the same
 """
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass
 from typing import Sequence
@@ -127,61 +131,12 @@ def _meta_cols(S: int, PS: int, DD: int):
     return m_s, m_ps, m_d, m_g
 
 
-_LEGACY_RT_FLAG = "--xla_cpu_use_thunk_runtime=false"
-
-
-def _maybe_prefer_legacy_cpu_runtime() -> None:
-    """Ask XLA:CPU for the legacy (pre-thunk) runtime before the backend
-    initializes.  The fused tick is a ~200-op loop body; the thunk
-    interpreter charges ~8µs of dispatch per op per tick, while the legacy
-    emitter runs the same HLO ~2.5x faster (measured on the tracked
-    serial-CPU host, see docs/simulator.md).  Best-effort only: if jax is
-    already initialized the flag is left alone, and
-    ``REPRO_BATCH_LEGACY_CPU_RT=0`` opts out (e.g. if a future jaxlib
-    drops the flag)."""
-    if os.environ.get("REPRO_BATCH_LEGACY_CPU_RT", "1") == "0":
-        return
-    import sys
-    mod = sys.modules.get("jax")
-    if mod is not None and getattr(mod, "_src", None) is not None:
-        try:  # backend already up? then mutating XLA_FLAGS is a no-op
-            from jax._src import xla_bridge
-            if xla_bridge._backends:
-                return
-        except Exception:
-            pass
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" not in flags:
-        os.environ["XLA_FLAGS"] = (flags + " " + _LEGACY_RT_FLAG).strip()
-
-
 def _jax():
     """Import jax lazily so jax-free consumers never pay for it."""
-    _maybe_prefer_legacy_cpu_runtime()
     import jax
     import jax.numpy as jnp
     from jax import lax
     return jax, jnp, lax
-
-
-_CACHE_DIR_SET = False
-
-
-def _maybe_enable_compile_cache() -> None:
-    """Best-effort persistent XLA compile cache (huge win for CI reruns)."""
-    global _CACHE_DIR_SET
-    if _CACHE_DIR_SET:
-        return
-    _CACHE_DIR_SET = True
-    path = os.environ.get("REPRO_JAX_CACHE_DIR",
-                          os.path.expanduser("~/.cache/repro-jax"))
-    try:
-        import jax
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass  # cache is an optimization, never a requirement
 
 
 def batch_supported(cfg: SimConfig) -> bool:
@@ -423,6 +378,19 @@ def _acap(ln: "_Lane") -> int:
     return ln.occupancy
 
 
+def _refill_rate(cfg: SimConfig) -> float:
+    """MRF bank slots per cycle (the scalar engines' ``_mrf_rate``)."""
+    return cfg.num_banks / max(cfg.mrf_cycles / 6.0, 1.0)
+
+
+def _refill_steps(cfg: SimConfig) -> int:
+    """Fewest cycles after which one refill fills an empty bucket."""
+    rate, n = _refill_rate(cfg), 0
+    while rate * n < cfg.num_banks:
+        n += 1
+    return n
+
+
 def _bucket(n: int, floor: int) -> int:
     """Next power-of-two >= n (>= floor): shape buckets bound recompiles."""
     b = floor
@@ -433,7 +401,9 @@ def _bucket(n: int, floor: int) -> int:
 
 def _build(lanes: Sequence[_Lane]):
     """Pad every lane's tables/config into batch arrays (numpy, 64-bit)."""
-    i32, i64, f64 = np.int32, np.int64, np.float64
+    from .f64bits import bits
+
+    i32, i64 = np.int32, np.int64
     K = _bucket(len(lanes), 2)
     W = _bucket(max(ln.cfg.num_warps for ln in lanes), 4)
     # Active-list width: cached designs cap it at `active_slots` (8), the
@@ -460,6 +430,9 @@ def _build(lanes: Sequence[_Lane]):
     _rfc_es = [ln.cfg.rfc_entries for ln in lanes if ln.cfg.design == "RFC"]
     E = max(2, *_rfc_es) if _rfc_es else 1
     IW = max(ln.cfg.issue_width for ln in lanes)
+    # token-bucket refill table width: past a lane's last entry a refill
+    # tops the bucket up to full whatever the gap
+    DT = _bucket(max(_refill_steps(ln.cfg) for ln in lanes) + 1, 8)
 
     m_s, m_ps, m_d, m_g = _meta_cols(S, PS, DD)
     MW = m_g + G                      # packed meta row width
@@ -483,18 +456,24 @@ def _build(lanes: Sequence[_Lane]):
         # per-interval table: [rounds, nfetch, nwb, has_op] (sentinel at IV)
         "ivt": np.zeros((K, IV + 1, 4), i32),
         "ivregs": np.full((K, IV + 1, GV), -1, i32),
-        # per-lane scalars
+        # per-interval prefetch latency: f64 bits and its int() truncation
+        "ivlat": np.zeros((K, IV + 1), i64), "ivlati": np.zeros((K, IV + 1), i64),
+        # per-lane scalars; f64 quantities travel as bits (see `f64bits`)
         "endpc": np.zeros(K, i32),
-        "mrfc": np.zeros(K, f64), "rfcc": np.zeros(K, f64),
-        "brf_f": np.zeros(K, f64), "wlat": np.zeros(K, f64),
-        "rate": np.zeros(K, f64), "l1h": np.zeros(K, f64),
-        "xbar": np.ones(K, f64), "banksf": np.zeros(K, f64),
-        "aluf": np.zeros(K, f64), "memf": np.zeros(K, f64),
+        "mrfc": np.zeros(K, i64), "rfcc": np.zeros(K, i64),
+        "brf_f": np.zeros(K, i64), "wlat": np.zeros(K, i64),
+        "aluf": np.zeros(K, i64), "aluw": np.zeros(K, i64),
+        "banksf": np.zeros(K, i64),
+        # bits of rate * dt, dt = 0..DT-1 cycles since the last refill
+        "refill": np.zeros((K, DT), i64),
+        # L1 hit <=> jitter hash h < hthr (h / 0xFFFF < l1_hit is monotone)
+        "hthr": np.zeros(K, i64),
+        # bits of mem_cycles * (1 + spread) for each (h >> 3) jitter value
+        "mjit": np.zeros((K, 0x2000), i64),
         "brf_i": np.zeros(K, i64), "l1c": np.zeros(K, i64),
         # dram_interval is a float on gpu.per_sm_configs shards (the per-SM
-        # effective interval is dram_interval*num_sms/partitions) — golden
-        # does the same arithmetic in Python floats, exactly representable
-        "thr": np.zeros(K, i64), "drint": np.zeros(K, f64),
+        # effective interval is dram_interval*num_sms/partitions)
+        "thr": np.zeros(K, i64), "drint": np.zeros(K, i64),
         "seed": np.zeros(K, i64), "maxc": np.zeros(K, i64),
         "iw": np.zeros(K, i32), "nw": np.zeros(K, i32),
         "rcap": np.zeros(K, i32), "acap": np.zeros(K, i32),
@@ -546,22 +525,30 @@ def _build(lanes: Sequence[_Lane]):
         design = cfg.design
         cached = design in _CACHED_DESIGNS
         rcap = ln.occupancy
-        co["mrfc"][k] = cfg.mrf_cycles
-        co["rfcc"][k] = float(cfg.rfc_cycles)
-        co["brf_f"][k] = float(cfg.base_rf_cycles)
-        co["wlat"][k] = (float(cfg.base_rf_cycles) if design == "Ideal"
-                         else cfg.mrf_cycles if design == "BL"
-                         else float(cfg.rfc_cycles))
-        co["rate"][k] = cfg.num_banks / max(cfg.mrf_cycles / 6.0, 1.0)
-        co["l1h"][k] = ln.workload.l1_hit
-        co["xbar"][k] = float(cfg.xbar_regs_per_cycle)
-        co["banksf"][k] = float(cfg.num_banks)
-        co["aluf"][k] = float(cfg.alu_cycles)
-        co["memf"][k] = float(cfg.mem_cycles)
+        # every float below is computed as the scalar engines compute it
+        # (numpy float64 rounds each operation as Python does)
+        lat = c.iv_rounds * cfg.mrf_cycles \
+            + c.iv_nfetch / cfg.xbar_regs_per_cycle
+        co["ivlat"][k, : nv + 1] = bits(lat)
+        co["ivlati"][k, : nv + 1] = lat.astype(i64)
+        wlat = (cfg.base_rf_cycles if design == "Ideal"
+                else cfg.mrf_cycles if design == "BL" else cfg.rfc_cycles)
+        co["mrfc"][k] = bits(cfg.mrf_cycles)
+        co["rfcc"][k] = bits(cfg.rfc_cycles)
+        co["brf_f"][k] = bits(cfg.base_rf_cycles)
+        co["wlat"][k] = bits(wlat)
+        co["aluf"][k] = bits(cfg.alu_cycles)
+        co["aluw"][k] = bits(cfg.alu_cycles + wlat)
+        co["banksf"][k] = bits(cfg.num_banks)
+        co["refill"][k] = bits(_refill_rate(cfg) * np.arange(DT))
+        co["hthr"][k] = np.count_nonzero(
+            np.arange(0x10000) / 0xFFFF < ln.workload.l1_hit)
+        spread = (np.arange(0x2000) / 0x1FFF - 0.5) * 0.6
+        co["mjit"][k] = bits(cfg.mem_cycles * (1.0 + spread))
         co["brf_i"][k] = cfg.base_rf_cycles
         co["l1c"][k] = cfg.l1_cycles
         co["thr"][k] = 2 * cfg.l1_cycles
-        co["drint"][k] = cfg.dram_interval
+        co["drint"][k] = bits(cfg.dram_interval)
         co["seed"][k] = cfg.seed
         co["maxc"][k] = cfg.max_cycles
         co["iw"][k] = cfg.issue_width
@@ -588,8 +575,8 @@ def _build(lanes: Sequence[_Lane]):
         "alive": np.zeros(K, bool),
         "budget": np.zeros(K, bool),
         "wf": wf,
-        "cf": np.zeros((K, W, 2 + S + PS), f64),
-        "rv": np.zeros((K, W, RVW, 2), f64),
+        "cf": np.zeros((K, W, 2 + S + PS), i64),
+        "rv": np.zeros((K, W, RVW, 2), i64),
         "act": np.zeros((K, A), i32),
         "na": np.zeros(K, i32),
         "res": np.zeros((K, W), bool),
@@ -597,9 +584,9 @@ def _build(lanes: Sequence[_Lane]):
         "ptr": np.zeros(K, i32),
         "pf": np.full((K, PF), _BIG, i64),
         "col": np.full((K, C), _BIG, i64),
-        "tok": np.zeros(K, f64),
+        "tok": np.zeros(K, i64),
         "mlast": np.zeros(K, i64),
-        "dnext": np.zeros(K, f64),
+        "dnext": np.zeros(K, i64),
         "rc": rc,
         "rcnt": np.zeros(K, i32),
         "rstamp": np.zeros(K, i64),
@@ -618,7 +605,7 @@ def _build(lanes: Sequence[_Lane]):
         st["ptr"][k] = ln.occupancy
         st["pf"][k, : cfg.max_inflight_prefetch] = 0
         st["col"][k, : cfg.num_collectors] = 0
-        st["tok"][k] = float(cfg.num_banks)
+        st["tok"][k] = bits(cfg.num_banks)
     return co, st
 
 
@@ -629,7 +616,9 @@ def _build(lanes: Sequence[_Lane]):
 def _run_jax(co, st):
     """Advance every lane to completion.  Traced+jitted once per shape."""
     _, jnp, lax = _jax()
-    i64, f64 = jnp.int64, jnp.float64
+    from . import f64bits as fb
+
+    i64 = jnp.int64
     K, W, NWF = st["wf"].shape
     A = st["act"].shape[1]
     E = st["rc"].shape[1]         # 1 <=> no RFC lane in this chunk (static)
@@ -651,15 +640,7 @@ def _run_jax(co, st):
     aI = jnp.arange(A)
     ctrI = jnp.arange(NWF - _F_LC)
     BIG = jnp.asarray(_BIG, i64)
-
-    def rnd(s, x):
-        """Round a float product before its consuming add.  XLA CPU
-        contracts a*b+c into one fma (single rounding), but the scalar
-        engines round the product first — a one-ulp difference that is
-        enough to flip a token-bucket comparison.  The select on a
-        loop-carried value cannot be folded away, so the intermediate is
-        materialized and rounded exactly like the Python arithmetic."""
-        return jnp.where(s["guard"] >= 0, x, 0.0)
+    NOEV = jnp.asarray(np.iinfo(np.int64).max, i64)   # "no next event"
 
     def refresh_cf(s, wid, mask, md):
         """Recompute the readiness-cache row for one selected warp per lane
@@ -671,10 +652,10 @@ def _run_jax(co, st):
         pidx = md[:, M_PS: M_PS + PS]                       # (K, PS)
         rvw = s["rv"][kk[:, None], wid[:, None], sidx]      # (K, S, 2)
         ts = rvw[:, :, 0]
-        fm = rvw[:, :, 1] > 0.0
+        fm = rvw[:, :, 1] > 0
         tp = s["rv"][kk[:, None], wid[:, None], R + 1 + pidx, 0]
         cmax = jnp.maximum(ts.max(axis=1), tp.max(axis=1))
-        cmem = jnp.where(fm, ts, 0.0).max(axis=1)
+        cmem = jnp.where(fm, ts, 0).max(axis=1)
         newcf = jnp.concatenate([cmax[:, None], cmem[:, None], ts, tp],
                                 axis=1)
         oldcf = s["cf"][kk, wid]
@@ -689,7 +670,7 @@ def _run_jax(co, st):
         slot = jnp.argmin(s["pf"], axis=1)
         freet = s["pf"][kk, slot]
         startt = jnp.maximum(s["cycle"], freet)
-        done = (startt.astype(f64) + lat).astype(i64)   # int(start + lat)
+        done = fb.floor(fb.add(fb.from_int(startt), lat))  # int(start + lat)
         s["pf"] = s["pf"].at[kk, slot].set(jnp.where(body, done, freet))
         return s, done
 
@@ -698,7 +679,7 @@ def _run_jax(co, st):
         regs = co["ivregs"][kk, ii]                     # (K, GV)
         vp = (regs >= 0) & body[:, None]
         ridx = jnp.where(vp, regs, RVW)                 # OOB: masked drop
-        val = jnp.where(vp, done[:, None].astype(f64), 0.0)
+        val = jnp.where(vp, fb.from_int(done)[:, None], 0)
         s["rv"] = s["rv"].at[kk[:, None], wid[:, None], ridx, 0].max(val)
         return s
 
@@ -726,11 +707,9 @@ def _run_jax(co, st):
             ivt = co["ivt"][kk, ii]                      # (K, 4)
             body = go & (ivt[:, 3] > 0)
             nf = ivt[:, 1].astype(i64)
-            lat = rnd(s, ivt[:, 0].astype(f64) * co["mrfc"]) \
-                + nf.astype(f64) / co["xbar"]
-            s, done = prefetch_slot(s, body, lat)
+            s, done = prefetch_slot(s, body, co["ivlat"][kk, ii])
             s["cpo"] += body.astype(i64)
-            s["cpc"] += jnp.where(body, lat.astype(i64), 0)
+            s["cpc"] += jnp.where(body, co["ivlati"][kk, ii], 0)
             s["cps"] += jnp.where(body, done - s["cycle"], 0)
             s["cm"] += jnp.where(body, nf, 0)
             s = prefetch_charge(s, wid, ii, body, done)
@@ -754,7 +733,7 @@ def _run_jax(co, st):
 
         return lax.while_loop(more, one, s)
 
-    def issue_one(s, picked, wsel):
+    def issue_one(s, picked, wsel, cycf):
         """The _issue body for one selected warp per lane, masked.
         Returns (state, instruction-issued, structural-stall)."""
         row = s["wf"][kk, wsel]                         # (K, NWF)
@@ -785,16 +764,16 @@ def _run_jax(co, st):
                          jnp.where(co["rfc"], n_miss, 0))
         do_bw = opnd & (n_bw > 0)
         refill = do_bw & (s["cycle"] > s["mlast"])
-        newtok = jnp.minimum(
-            co["banksf"],
-            s["tok"] + rnd(s, co["rate"]
-                           * (s["cycle"] - s["mlast"]).astype(f64)))
+        gap = jnp.clip(s["cycle"] - s["mlast"], 0, co["refill"].shape[1] - 1)
+        newtok = jnp.minimum(co["banksf"],
+                             fb.add(s["tok"], co["refill"][kk, gap]))
         tok = jnp.where(refill, newtok, s["tok"])
         s["mlast"] = jnp.where(refill, s["cycle"], s["mlast"])
-        bw_ok = ~do_bw | (tok >= n_bw.astype(f64))
+        n_bwf = fb.from_int(n_bw)
+        bw_ok = ~do_bw | (tok >= n_bwf)
         # tokens are consumed before the collector attempt (and leak if the
         # collector then fails — the scalar engines' exact semantics)
-        s["tok"] = jnp.where(do_bw & bw_ok, tok - n_bw.astype(f64), tok)
+        s["tok"] = jnp.where(do_bw & bw_ok, fb.sub(tok, n_bwf), tok)
         cslot = jnp.argmin(s["col"], axis=1)
         cfree = s["col"][kk, cslot]
         ok = opnd & bw_ok & (cfree <= s["cycle"])
@@ -849,19 +828,20 @@ def _run_jax(co, st):
         mops = row[:, F_MO]
         h = (wsel.astype(i64) * 2654435761 + mops * 40503
              + co["seed"] * 97) & 0xFFFF
-        hit = (h.astype(f64) / 65535.0) < co["l1h"]
-        spread = rnd(s, ((h >> 3).astype(f64) / 8191.0 - 0.5) * 0.6)
-        dstart = jnp.maximum(s["cycle"].astype(f64), s["dnext"])
-        s["dnext"] = jnp.where(ldo & ~hit, dstart + co["drint"], s["dnext"])
+        hit = h < co["hthr"]
+        dstart = jnp.maximum(cycf, s["dnext"])
+        s["dnext"] = jnp.where(ldo & ~hit, fb.add(dstart, co["drint"]),
+                               s["dnext"])
         mlat = jnp.where(hit, co["l1c"],
-                         (dstart - s["cycle"].astype(f64)
-                          + rnd(s, co["memf"] * (1.0 + spread))).astype(i64))
+                         fb.floor(fb.add(fb.sub(dstart, cycf),
+                                         co["mjit"][kk, h >> 3])))
         # writeback chain: done_at accumulates exactly like the scalar code
-        base = s["cycle"].astype(f64) + read_lat
         is_set = kind == _OP_SET
-        da = jnp.where(is_set, base + co["aluf"],
-                       jnp.where(is_ld, base + (mlat.astype(f64) + co["wlat"]),
-                                 base + (co["aluf"] + co["wlat"])))
+        da = fb.add(fb.add(cycf, read_lat),
+                    jnp.where(is_set, co["aluf"],
+                              jnp.where(is_ld,
+                                        fb.add(fb.from_int(mlat), co["wlat"]),
+                                        co["aluw"])))
         # dst-register + dst-predicate writeback: ONE scatter into the
         # unified (reg | pred) value plane, masked rows dropped via OOB
         pd = md[:, M_PDST]
@@ -874,8 +854,8 @@ def _run_jax(co, st):
         vt = jnp.concatenate(
             [jnp.broadcast_to(da[:, None], ond.shape), da[:, None]], axis=1)
         vm = jnp.concatenate(
-            [(ond & is_ld[:, None]).astype(f64),
-             jnp.zeros((K, 1), f64)], axis=1)
+            [(ond & is_ld[:, None]).astype(i64),
+             jnp.zeros((K, 1), i64)], axis=1)
         s["rv"] = s["rv"].at[kk[:, None], wsel[:, None], wix].set(
             jnp.stack([vt, vm], axis=2))
         happened = bra | ext | ok
@@ -913,11 +893,9 @@ def _run_jax(co, st):
         ivt = co["ivt"][kk, ii]
         body = go & (ivt[:, 3] > 0)
         nf = ivt[:, 1].astype(i64)
-        lat = rnd(s, ivt[:, 0].astype(f64) * co["mrfc"]) \
-            + nf.astype(f64) / co["xbar"]
-        s, done = prefetch_slot(s, body, lat)
+        s, done = prefetch_slot(s, body, co["ivlat"][kk, ii])
         s["cpo"] += body.astype(i64)
-        s["cpc"] += jnp.where(body, lat.astype(i64), 0)
+        s["cpc"] += jnp.where(body, co["ivlati"][kk, ii], 0)
         s["cps"] += jnp.where(body, done - s["cycle"], 0)
         s["cm"] += jnp.where(body, nf, 0)
         s = prefetch_charge(s, wsel, ii, body, done)
@@ -972,7 +950,9 @@ def _run_jax(co, st):
                          (aI[None, :] - (s["cycle"] % nz)[:, None])
                          % nz[:, None], BIG)
         ndacc = jnp.zeros((K, A), bool)
-        msacc = jnp.zeros((K, A), f64)
+        msacc = jnp.zeros((K, A), i64)
+        cycf = fb.from_int(s["cycle"])
+        thrf = fb.from_int(s["cycle"] + co["thr"])
         issue_any = jnp.zeros((K,), bool)
         struct = jnp.zeros((K,), bool)
         for j in range(IW):
@@ -986,10 +966,8 @@ def _run_jax(co, st):
             # readiness/blockedness from the cached per-warp planes — no
             # per-slot operand gathers (scalar `_refresh_ready` semantics:
             # a warp's operand times only change when IT issues/prefetches)
-            cyc = s["cycle"].astype(f64)[:, None]
-            ready = isact & ~atend & (cfa[:, :, 0] <= cyc)
-            thr = (s["cycle"] + co["thr"]).astype(f64)[:, None]
-            blocked = jnp.where(cfa[:, :, 1] > thr, cfa[:, :, 1], 0.0)
+            ready = isact & ~atend & (cfa[:, :, 0] <= cycf[:, None])
+            blocked = jnp.where(cfa[:, :, 1] > thrf[:, None], cfa[:, :, 1], 0)
             rrk = jnp.where(ready & slot_on[:, None], rank, BIG)
             crank = rrk.min(axis=1)
             picked = (crank < BIG) & slot_on
@@ -998,14 +976,14 @@ def _run_jax(co, st):
             ndacc = ndacc | (visited & isact & atend)
             # scanned warps blocked on long memory: deactivation candidates
             ms = visited & isact & ~atend & ~ready & (blocked > 0)
-            msacc = jnp.maximum(msacc, jnp.where(ms, blocked, 0.0))
+            msacc = jnp.maximum(msacc, jnp.where(ms, blocked, 0))
             wsel = s["act"][kk, jnp.argmin(rrk, axis=1)]
-            s, happened, sfail = issue_one(s, picked, wsel)
+            s, happened, sfail = issue_one(s, picked, wsel, cycf)
             issue_any = issue_any | happened
             struct = struct | sfail
         s["wf"] = s["wf"].at[kk[:, None], wida, F_ST].max(
             jnp.where(ndacc, DONE, 0))
-        stall_until = jnp.zeros((K, W), f64).at[kk[:, None], wida].max(msacc)
+        stall_until = jnp.zeros((K, W), i64).at[kk[:, None], wida].max(msacc)
         # two-level deactivation (cached designs swap stalled warps out)
         stp2 = s["wf"][:, :, F_ST]
         de = (stall_until > 0) & (stp2 == ACTIVE) \
@@ -1018,7 +996,7 @@ def _run_jax(co, st):
         s["cm"] += nwb
         s["wf"] = s["wf"].at[:, :, F_ST].set(jnp.where(de, WAIT, stp2))
         s["wf"] = s["wf"].at[:, :, F_RA].set(
-            jnp.where(de, stall_until.astype(i64), s["wf"][:, :, F_RA]))
+            jnp.where(de, fb.floor(stall_until), s["wf"][:, :, F_RA]))
         s["wf"] = s["wf"].at[:, :, F_IV].set(jnp.where(de, -1, ivv))
         # compact the active list: drop deactivated (WAIT) + retired (DONE).
         # Stable compaction = cumsum of keepers + dropped-OOB scatter (the
@@ -1057,7 +1035,6 @@ def _run_jax(co, st):
         stc = s["wf"][:, :, F_ST]
         pcw = s["wf"][:, :, F_PC]
         livew = (stc == ACTIVE) & (pcw < co["endpc"][:, None])
-        cycf = s["cycle"].astype(f64)
         cmaxw = s["cf"][:, :, 0]
         cmemw = s["cf"][:, :, 1]
         saw_pf = (stc == PREFETCH).any(axis=1)
@@ -1071,20 +1048,18 @@ def _run_jax(co, st):
               jnp.where(saw_dep, _CAT_INDEX["alu_dep"],
                         _CAT_INDEX["scheduler_idle"])))))
         cyc = s["cycle"]
-        INF = jnp.inf
         colf = s["col"].min(axis=1)
-        c1 = jnp.where(colf > cyc, colf.astype(f64), INF)
+        c1 = jnp.where(colf > cyc, colf, NOEV)
         wnp = s["res"] & ((stc == WAIT) | (stc == PREFETCH))
-        c2 = jnp.where(wnp, s["wf"][:, :, F_RA].astype(f64), INF).min(axis=1)
-        tsv = s["cf"][:, :, 2: 2 + S]
-        tpv = s["cf"][:, :, 2 + S:]
-        tsrc = jnp.where(livew[:, :, None] & (tsv > cycf[:, None, None]),
-                         tsv, INF).min(axis=(1, 2))
-        tpd = jnp.where(livew[:, :, None] & (tpv > cycf[:, None, None]),
-                        tpv, INF).min(axis=(1, 2))
-        best = jnp.minimum(jnp.minimum(c1, c2), jnp.minimum(tsrc, tpd))
-        nxt = jnp.where(jnp.isinf(best), cyc + 1,
-                        jnp.maximum(best.astype(i64), cyc + 1))
+        c2 = jnp.where(wnp, s["wf"][:, :, F_RA], NOEV).min(axis=1)
+        # operand times: the earliest pending one, floored (floor and min
+        # commute)
+        tv = s["cf"][:, :, 2:]
+        tmin = jnp.where(livew[:, :, None] & (tv > cycf[:, None, None]),
+                         tv, fb.INF).min(axis=(1, 2))
+        c3 = jnp.where(tmin == fb.INF, NOEV, fb.floor(tmin))
+        best = jnp.minimum(jnp.minimum(c1, c2), c3)
+        nxt = jnp.where(best == NOEV, cyc + 1, jnp.maximum(best, cyc + 1))
         delta = jnp.where(issue_any, 1, nxt - cyc)
         cati = jnp.where(issue_any, 0, cat)
         oh = (jnp.arange(NCAT)[None, :] == cati[:, None]) & adv[:, None]
@@ -1136,7 +1111,6 @@ def _aot_compile(co, st):
     fn = _COMPILED.get(sig)
     if fn is None:
         jax, _, _ = _jax()
-        _maybe_enable_compile_cache()
         t0 = time.perf_counter()
         fn = jax.jit(_run_jax).lower(co, st).compile()
         RUN_STATS["compile_s"] += time.perf_counter() - t0
@@ -1146,10 +1120,9 @@ def _aot_compile(co, st):
 
 
 def _run_lanes(lanes: Sequence[_Lane]) -> list:
-    from jax.experimental import enable_x64
-
+    jax, _, _ = _jax()
     co, st = _build(lanes)
-    with enable_x64():  # the scalar engines do Python-f64 arithmetic
+    with jax.enable_x64(True):  # int64 state: cycles, stamps, f64 bits
         fn = _aot_compile(co, st)
         t0 = time.perf_counter()
         out = fn(co, st)

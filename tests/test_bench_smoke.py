@@ -13,7 +13,7 @@ import sys
 
 import pytest
 
-from repro.kernels._compat import jax_subprocess_env
+from jax_subprocess import jax_subprocess_env
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 

@@ -1,0 +1,149 @@
+"""Ahead-of-time compiles for a described TPU v5e, at real widths.
+
+Nothing runs on a chip here: the TPU compiler, which is installed with jax,
+compiles for a 2x2 v5e topology that is described, not attached.  That
+catches what Pallas interpret mode never checks (block alignment to the
+(8, 128) tiling, scoped-VMEM limits, kernels that cannot lower) and what a
+CPU run never sees (a step that does not fit 16 GB of HBM).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and under pytest-xdist
+every worker imports this file.  Keep all such compiles in this one file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+GiB = 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_hlo(fn, *args) -> str:
+    txt = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in txt  # the Pallas kernel, not a fallback
+    return txt
+
+
+@pytest.mark.parametrize("M,K,N,dtype", [
+    (4096, 1024, 3072, jnp.bfloat16),     # qwen3-0.6b MLP up-projection
+    (4096, 14336, 4096, jnp.float32),     # 14336-wide K in f32
+])
+def test_ltrf_matmul_compiles(one_chip, M, K, N, dtype):
+    from repro.kernels.ltrf_matmul.ops import ltrf_matmul
+
+    _kernel_hlo(ltrf_matmul, _sds((M, K), dtype, one_chip),
+                _sds((K, N), dtype, one_chip))
+
+
+def test_flash_attention_compiles(one_chip):
+    from repro.kernels.flash_attention.ops import flash_attention
+
+    q = _sds((1, 16, 2048, 128), jnp.bfloat16, one_chip)   # qwen3 GQA 16:8
+    kv = _sds((1, 8, 2048, 128), jnp.bfloat16, one_chip)
+    _kernel_hlo(flash_attention, q, kv, kv)
+
+
+def test_ssd_scan_compiles(one_chip):
+    from repro.configs import get_arch
+    from repro.kernels.ssd_scan.ops import ssd_scan
+
+    cfg = get_arch("mamba2-1.3b")
+    H = cfg.ssm_expand * cfg.d_model // cfg.ssm_headdim
+    B, S, P, N = 1, 2048, cfg.ssm_headdim, cfg.ssm_state
+    assert (H, P, N, cfg.ssm_chunk) == (64, 64, 128, 256)
+    _kernel_hlo(lambda *a: ssd_scan(*a, chunk=cfg.ssm_chunk),
+                _sds((B, S, H, P), jnp.bfloat16, one_chip),
+                _sds((B, S, H), jnp.float32, one_chip),
+                _sds((H,), jnp.float32, one_chip),
+                _sds((B, S, N), jnp.bfloat16, one_chip),
+                _sds((B, S, N), jnp.bfloat16, one_chip))
+
+
+def test_batch_engine_chunk_compiles(one_chip):
+    """One 4-lane chunk of the jitted lockstep simulator, with int64 state
+    and no floating point (a TPU's f64 is emulated and not IEEE-exact)."""
+    from repro.sim import design_config
+    from repro.sim.batch import _Lane, _build, _encode_plan, _occupancy, _run_jax
+    from repro.workloads import get_workload
+
+    lanes = []
+    for name in ("srad", "kmeans"):
+        for design in ("BL", "RFC"):    # the token-bucket designs
+            w = get_workload(name)
+            cfg = design_config(design, table2_config=7, num_warps=16)
+            lanes.append(_Lane(w, cfg, _encode_plan(w, cfg), _occupancy(w, cfg)))
+    co, st = _build(lanes)
+    with jax.enable_x64(True):
+        args = [{k: _sds(v.shape, v.dtype, one_chip) for k, v in d.items()}
+                for d in (co, st)]
+        lowered = jax.jit(_run_jax).lower(*args)
+        assert lowered.compile().as_text()
+    assert "f64" not in lowered.as_text()
+
+
+def test_qwen3_train_step_fits_one_chip(topo):
+    """The full-width, full-depth qwen3-0.6b step (batch 4 x seq 512, AdamW)
+    that `chip_smoke.py` trains fits one v5e's 16 GB with room to spare."""
+    from repro.configs import get_arch
+    from repro.distributed.sharding import default_rules, shardings_for
+    from repro.launch.mesh import make_host_mesh
+    from repro.optim.adamw import AdamWConfig
+    from repro.runtime.train_step import (
+        batch_axes_for, batch_shardings, build_train_step, make_train_state,
+    )
+
+    cfg = get_arch("qwen3-0.6b")
+    rules = default_rules(make_host_mesh(devices=topo.devices[:1]))
+    axes = {}
+
+    def init(key):
+        state, axes["state"] = make_train_state(cfg, key)
+        return state
+
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
+    st_sh = shardings_for(rules, axes["state"], shapes)
+    state = jax.tree.map(lambda s, sh: _sds(s.shape, s.dtype, sh),
+                         shapes, st_sh)
+    b_sh = batch_shardings(rules, batch_axes_for(cfg, "train"))
+    batch = {k: _sds((4, 512), jnp.int32, sh) for k, sh in b_sh.items()}
+    assert all(isinstance(s, NamedSharding) for s in b_sh.values())
+    step = build_train_step(cfg, rules, AdamWConfig(total_steps=3))
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 14 * GiB, total / GiB

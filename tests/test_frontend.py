@@ -15,7 +15,7 @@ from repro.core.intervals import form_register_intervals
 from repro.core.ir import back_edges, parse_asm, reachable_blocks
 from repro.frontend.regalloc import allocate_registers
 from repro.frontend.workloads import TRACED_NAMES, build_traced_workload
-from repro.kernels._compat import jax_subprocess_env
+from jax_subprocess import jax_subprocess_env
 from repro.sim import DESIGNS, design_config, simulate
 from repro.sim.golden import golden_simulate
 from repro.workloads import (WORKLOADS, Workload, get_workload,

@@ -8,7 +8,9 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.ltrf_matmul.ops import ltrf_matmul, matmul_plan, pick_blocks
+from repro.kernels.ltrf_matmul.ops import (
+    VMEM_LIMIT, ltrf_matmul, matmul_plan, pick_blocks, vmem_bytes,
+)
 from repro.kernels.ltrf_matmul.ref import matmul_ref
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_ref
@@ -72,8 +74,10 @@ def test_matmul_plan_conflict_free():
 def test_pick_blocks_mxu_aligned():
     bm, bk, bn = pick_blocks(4096, 5120, 17920)
     assert bm % 128 == bk % 128 == bn % 128 == 0
-    ws = bm * bk * 2 + 2 * bk * bn * 2 + bm * bn * 4 + bm * bn * 2
-    assert ws <= 96 * 2 ** 20
+    assert vmem_bytes(bm, bk, bn, 2) <= VMEM_LIMIT
+    # f32 at a 14336-wide K: the double-buffered tiles count at 4 bytes
+    bm, bk, bn = pick_blocks(4096, 14336, 4096, dtype_bytes=4)
+    assert vmem_bytes(bm, bk, bn, 4) <= VMEM_LIMIT
 
 
 # ---------------------------------------------------------------------------

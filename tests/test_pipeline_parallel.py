@@ -6,7 +6,7 @@ import subprocess
 import sys
 import textwrap
 
-from repro.kernels._compat import jax_subprocess_env
+from jax_subprocess import jax_subprocess_env
 
 SCRIPT = textwrap.dedent("""
     import os

@@ -87,9 +87,10 @@ def test_chunk_lanes_groups_by_shape():
 
 def test_fma_contraction_regression_pin():
     """BL/kmeans at Table-2 #7, 16 warps: the exact case where XLA's CPU
-    FMA contraction silently changed a token-bucket float compare until the
-    engine's mul-add sites were made contraction-proof.  Full-structure
-    equality (breakdown included) with the event engine."""
+    FMA contraction, and later a TPU's emulated f64, silently changed a
+    token-bucket float compare.  The engine now tabulates the products on
+    the host and adds on f64 bit patterns (`repro.sim.f64bits`).
+    Full-structure equality (breakdown included) with the event engine."""
     w = WORKLOADS["kmeans"]
     cfg = design_config("BL", table2_config=7, num_warps=16)
     assert simulate_one(w, cfg) == simulate(w, cfg)
@@ -235,15 +236,16 @@ def test_sweep_batch_mode_policy(tmp_path, monkeypatch):
 
 
 def test_auto_batch_threshold_platform_policy(monkeypatch):
-    """'auto' mode's engage bar: low on a loaded non-CPU jax backend, the
-    compile-amortizing CPU bar otherwise — and the probe itself must never
-    import jax (a cache lookup should not pay a multi-second import)."""
+    """'auto' mode's engage bar: low on a loaded non-CPU jax backend, and
+    no bar at all (never batch) on CPU or before jax is loaded — and the
+    probe itself must never import jax (a cache lookup should not pay a
+    multi-second import)."""
     import sys
 
     from repro.serving import sweep as S
 
     monkeypatch.delitem(sys.modules, "jax", raising=False)
-    assert S._auto_batch_threshold() == S._MIN_AUTO_BATCH_CPU
+    assert S._auto_batch_threshold() is None
     assert "jax" not in sys.modules  # probe did not import it
 
     class _Dev:
@@ -260,7 +262,7 @@ def test_auto_batch_threshold_platform_policy(monkeypatch):
     monkeypatch.setitem(sys.modules, "jax", _FakeJax("gpu"))
     assert S._auto_batch_threshold() == S._MIN_AUTO_BATCH
     monkeypatch.setitem(sys.modules, "jax", _FakeJax("cpu"))
-    assert S._auto_batch_threshold() == S._MIN_AUTO_BATCH_CPU
+    assert S._auto_batch_threshold() is None
 
 
 @pytest.mark.slow

@@ -93,10 +93,9 @@ def test_walkthrough_end_to_end():
 
 def test_plan_drives_kernel_blocks():
     """The interval plan and the kernel block picker agree on VMEM budgets."""
-    from repro.kernels.ltrf_matmul.ops import VMEM_BUDGET, matmul_plan
+    from repro.kernels.ltrf_matmul.ops import VMEM_LIMIT, matmul_plan, vmem_bytes
     plan, (bm, bk, bn) = matmul_plan(4096, 17920, 5120)
-    ws = bm * bk * 2 + 2 * bk * bn * 2 + bm * bn * 4 + bm * bn * 2
-    assert ws <= VMEM_BUDGET
+    assert vmem_bytes(bm, bk, bn, 2) <= VMEM_LIMIT
     assert plan.max_interval_bytes() <= plan.vmem_budget + plan.tile_bytes
 
 
