@@ -3,6 +3,10 @@
 Params are plain pytrees of jnp arrays.  Every init function returns
 (params, logical_axes) where logical_axes mirrors the params pytree with
 tuples of logical axis names consumed by repro.distributed.sharding.
+
+The attention and MLP blocks run under the ``jax.named_scope``s ``attn``
+and ``mlp``: op metadata only, which a device trace reads back to put each
+operation (forward, remat recompute and backward alike) down to its block.
 """
 from __future__ import annotations
 
@@ -152,6 +156,7 @@ def causal_attention(q, k, v, q_block: int = 512, q_offset=None):
     return out.astype(q.dtype)
 
 
+@jax.named_scope("attn")
 def attention_block(params, x, *, n_heads, n_kv, head_dim, positions,
                     qk_norm=False, rope_theta=10000.0, norm_eps=1e-5,
                     q_block=512):
@@ -204,6 +209,7 @@ def init_mlp(key, d_model, d_ff, dtype):
     return params, axes
 
 
+@jax.named_scope("mlp")
 def mlp_block(params, x):
     h = jax.nn.silu(x @ params["w_gate"]) * (x @ params["w_up"])
     return h @ params["w_down"]

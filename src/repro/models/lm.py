@@ -6,6 +6,11 @@ axis) so the lowered HLO stays small enough to compile 512-device meshes on
 one CPU host.  Activation/param logical-axis annotations flow through
 `repro.distributed.sharding.constrain`.
 
+The training step's operations carry fixed ``jax.named_scope`` names,
+shared by every family: ``embed``, ``attn``, ``mlp`` (the MoE block too),
+``norm`` and ``head_loss`` here and in `layers`, ``adamw`` in the
+optimizer.  They are op metadata only; a device trace reads them back.
+
 Entry points:
   init_params(cfg, key)            -> (params, logical_axes)
   loss_fn(params, batch, cfg)      -> (scalar loss, metrics)  [train/prefill]
@@ -112,19 +117,27 @@ def init_params(cfg: ArchConfig, key):
 # blocks (forward)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("norm")
+def _norm(x, weight, eps):
+    """A block's or the final RMS norm (attention's q/k norms stay under
+    ``attn``)."""
+    return rms_norm(x, weight, eps)
+
+
 def _dense_block(cfg: ArchConfig, p, x, positions):
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
+    h = _norm(x, p["norm1"], cfg.norm_eps)
     h = attention_block(p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                         head_dim=cfg.hd, positions=positions,
                         qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
                         norm_eps=cfg.norm_eps, q_block=cfg.q_block)
     x = x + h
     x = constrain(x, ("act_batch", "act_seq", "act_embed"))
-    h = rms_norm(x, p["norm2"], cfg.norm_eps)
+    h = _norm(x, p["norm2"], cfg.norm_eps)
     if cfg.family == "moe":
-        h, aux = moe_block(p["moe"], h, top_k=cfg.top_k,
-                           capacity_factor=cfg.capacity_factor,
-                           groups=cfg.moe_groups)
+        with jax.named_scope("mlp"):
+            h, aux = moe_block(p["moe"], h, top_k=cfg.top_k,
+                               capacity_factor=cfg.capacity_factor,
+                               groups=cfg.moe_groups)
     else:
         h, aux = mlp_block(p["mlp"], h), jnp.zeros((), jnp.float32)
     x = x + h
@@ -133,7 +146,7 @@ def _dense_block(cfg: ArchConfig, p, x, positions):
 
 
 def _ssm_block(cfg: ArchConfig, p, x):
-    h = rms_norm(x, p["norm"], cfg.norm_eps)
+    h = _norm(x, p["norm"], cfg.norm_eps)
     h = mamba2_block(p["mixer"], h, d_state=cfg.ssm_state,
                      headdim=cfg.ssm_headdim, expand=cfg.ssm_expand,
                      chunk=cfg.ssm_chunk, norm_eps=cfg.norm_eps)
@@ -142,13 +155,13 @@ def _ssm_block(cfg: ArchConfig, p, x):
 
 
 def _shared_block(cfg: ArchConfig, p, x, positions):
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
+    h = _norm(x, p["norm1"], cfg.norm_eps)
     h = attention_block(p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                         head_dim=cfg.hd, positions=positions,
                         rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
                         q_block=cfg.q_block)
     x = x + h
-    h = rms_norm(x, p["norm2"], cfg.norm_eps)
+    h = _norm(x, p["norm2"], cfg.norm_eps)
     x = x + mlp_block(p["mlp"], h)
     return constrain(x, ("act_batch", "act_seq", "act_embed"))
 
@@ -212,7 +225,7 @@ def forward(params, cfg: ArchConfig, x, positions):
             x, _ = jax.lax.scan(lambda c, p: (blk(c, p), None), x, tail)
     else:
         raise ValueError(cfg.family)
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+    return _norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
 def _forward_unrolled(params, cfg: ArchConfig, x, positions):
@@ -238,9 +251,10 @@ def _forward_unrolled(params, cfg: ArchConfig, x, positions):
                 x = _shared_block(cfg, params["shared_attn"], x, positions)
     else:
         raise ValueError(cfg.family)
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+    return _norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
+@jax.named_scope("embed")
 def embed_inputs(params, cfg: ArchConfig, batch):
     """Family-specific input embedding.  Returns (x, positions, label_info)."""
     if cfg.family == "vlm":
@@ -258,21 +272,25 @@ def embed_inputs(params, cfg: ArchConfig, batch):
     return x, positions
 
 
-def loss_fn(params, batch, cfg: ArchConfig):
-    """Causal LM loss over the batch.  Returns (loss, metrics)."""
-    x, positions = embed_inputs(params, cfg, batch)
-    h, aux = forward(params, cfg, x, positions)
-    labels = batch["labels"]
+@jax.named_scope("head_loss")
+def _head_loss(params, cfg: ArchConfig, h, labels):
+    """Next-token cross-entropy of the LM head's logits."""
     if cfg.family == "audio":
         B, S, D = h.shape
         logits = (h @ params["lm_head"]).reshape(B, S, cfg.n_codebooks, cfg.vocab)
         logits = logits[:, :-1]
         lbl = labels[:, :, 1:].transpose(0, 2, 1)  # (B,S-1,K)
-        loss = cross_entropy(logits, lbl)
-    else:
-        logits = h @ params["lm_head"]
-        logits = constrain(logits, ("act_batch", "act_seq", "act_vocab"))
-        loss = cross_entropy(logits[:, :-1], labels[:, 1:])
+        return cross_entropy(logits, lbl)
+    logits = h @ params["lm_head"]
+    logits = constrain(logits, ("act_batch", "act_seq", "act_vocab"))
+    return cross_entropy(logits[:, :-1], labels[:, 1:])
+
+
+def loss_fn(params, batch, cfg: ArchConfig):
+    """Causal LM loss over the batch.  Returns (loss, metrics)."""
+    x, positions = embed_inputs(params, cfg, batch)
+    h, aux = forward(params, cfg, x, positions)
+    loss = _head_loss(params, cfg, h, batch["labels"])
     total = loss + 0.01 * aux
     return total, {"loss": loss, "aux_loss": aux}
 

@@ -63,8 +63,10 @@ def global_norm(tree) -> jnp.ndarray:
     return jnp.sqrt(jnp.sum(jnp.stack(leaves)))
 
 
+@jax.named_scope("adamw")
 def adamw_update(cfg: AdamWConfig, params, grads, state):
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics).  Runs under the named
+    scope ``adamw``, the global norm included."""
     step = state["step"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9))

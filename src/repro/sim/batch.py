@@ -80,6 +80,7 @@ the scalar `_next_event` — with the skipped cycles charged to the same
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass
 from typing import Sequence
@@ -590,7 +591,10 @@ def _build(lanes: Sequence[_Lane]):
         "rc": rc,
         "rcnt": np.zeros(K, i32),
         "rstamp": np.zeros(K, i64),
-        "bd": np.zeros((K, len(CYCLE_CATEGORIES)), i64),
+        # cycles per attribution category, then one column counting the
+        # ticks the lane entered alive (lockstep occupancy; no result
+        # field reads it)
+        "bd": np.zeros((K, len(CYCLE_CATEGORIES) + 1), i64),
         "ch": np.zeros(K, i64), "ca": np.zeros(K, i64),
         "cm": np.zeros(K, i64), "cpo": np.zeros(K, i64),
         "cpc": np.zeros(K, i64), "cps": np.zeros(K, i64),
@@ -923,6 +927,7 @@ def _run_jax(co, st):
 
     def tick(s):
         s["guard"] = s["guard"] + 1
+        entered = s["alive"]
         # cycle-budget watchdog: freeze the lane at the identical cycle the
         # scalar engines raise SimBudgetExceeded
         exceed = s["alive"] & (co["maxc"] > 0) & (s["cycle"] > co["maxc"])
@@ -1063,12 +1068,12 @@ def _run_jax(co, st):
         delta = jnp.where(issue_any, 1, nxt - cyc)
         cati = jnp.where(issue_any, 0, cat)
         oh = (jnp.arange(NCAT)[None, :] == cati[:, None]) & adv[:, None]
-        s["bd"] = s["bd"] + jnp.where(oh, delta[:, None], 0)
+        # the alive-tick counter rides in the breakdown's update: a carried
+        # vector of its own cost the loop 3 us a tick on a v5e (0.45%)
+        s["bd"] = s["bd"] + jnp.concatenate(
+            [jnp.where(oh, delta[:, None], 0), entered[:, None].astype(i64)],
+            axis=1)
         s["cycle"] = cyc + jnp.where(adv, delta, 0)
-        if _DEBUG_HOOK is not None:  # debug-only tracing (no jit cost when None)
-            _DEBUG_HOOK({"cycle": cyc, "issue": issue_any, "cat": cati,
-                         "delta": delta, "struct": struct, "na": s["na"],
-                         "act": s["act"], "s": s})
         return s
 
     def running(s):
@@ -1077,16 +1082,18 @@ def _run_jax(co, st):
     return lax.while_loop(running, tick, st)
 
 
-# Eager-only per-tick trace hook (set under jax.disable_jit(); checked at
-# trace time, so the jitted path never pays for it).
-_DEBUG_HOOK = None
-
 # Launch accounting for the perf ledger: XLA compile wall vs steady-state
 # simulation wall, plus the fused-loop tick count (how hard the
 # event-horizon skip is working).  `bench_sim` snapshots this around its
-# batch A/B so `BENCH_sim.json` can report `compile_s` separately.
+# batch A/B so `BENCH_sim.json` can report `compile_s` separately.  The
+# host phases around the launches (plan encoding, packing, extraction) have
+# their own seconds, and ``lane_ticks`` / ``lane_slots`` count the ticks
+# real lanes entered alive against the ticks they were carried (lockstep
+# occupancy).  Each phase is also a profiler span (`_phase`).
 RUN_STATS = {"compile_s": 0.0, "run_s": 0.0,
-             "compiles": 0, "launches": 0, "ticks": 0}
+             "compiles": 0, "launches": 0, "ticks": 0,
+             "encode_s": 0.0, "build_s": 0.0, "extract_s": 0.0,
+             "lane_ticks": 0, "lane_slots": 0}
 
 
 def reset_run_stats() -> dict:
@@ -1094,6 +1101,20 @@ def reset_run_stats() -> dict:
     for k, v in RUN_STATS.items():
         RUN_STATS[k] = type(v)(0)
     return RUN_STATS
+
+
+@contextlib.contextmanager
+def _phase(span: str, stat: str):
+    """One host phase: a profiler span named ``span`` (on the profiler's
+    clock, beside the device's operations) whose wall seconds add to
+    ``RUN_STATS[stat]``."""
+    jax, _, _ = _jax()
+    with jax.profiler.TraceAnnotation(span):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            RUN_STATS[stat] += time.perf_counter() - t0
 
 
 _COMPILED: dict = {}
@@ -1121,18 +1142,22 @@ def _aot_compile(co, st):
 
 def _run_lanes(lanes: Sequence[_Lane]) -> list:
     jax, _, _ = _jax()
-    co, st = _build(lanes)
+    with _phase("repro.sim.build", "build_s"):
+        co, st = _build(lanes)
     with jax.enable_x64(True):  # int64 state: cycles, stamps, f64 bits
         fn = _aot_compile(co, st)
-        t0 = time.perf_counter()
-        out = fn(co, st)
-        out = {k: np.asarray(v) for k, v in out.items()}
-        RUN_STATS["run_s"] += time.perf_counter() - t0
+        with _phase("repro.sim.launch", "run_s"):
+            out = fn(co, st)
+            out = {k: np.asarray(v) for k, v in out.items()}
         RUN_STATS["launches"] += 1
         RUN_STATS["ticks"] += int(out["guard"])
+        # the `_bucket` padding lanes past len(lanes) are never alive
+        RUN_STATS["lane_ticks"] += int(out["bd"][:len(lanes), -1].sum())
+        RUN_STATS["lane_slots"] += len(lanes) * int(out["guard"])
     if out["alive"].any():
         raise RuntimeError("batch simulator wedged")
-    return [_extract(ln, i, out) for i, ln in enumerate(lanes)]
+    with _phase("repro.sim.extract", "extract_s"):
+        return [_extract(ln, i, out) for i, ln in enumerate(lanes)]
 
 
 def _extract(lane: _Lane, i: int, out: dict):
@@ -1189,22 +1214,27 @@ def run_batch(jobs: Sequence[tuple[Workload, SimConfig]], *,
     outcomes: list = [None] * len(jobs)
     lanes: list[_Lane] = []
     idxs: list[int] = []
-    for i, (w, cfg) in enumerate(jobs):
-        if batch_supported(cfg):
-            parse_interval_strategy(cfg.interval_strategy)  # raise like engine
-            code = _encode_plan(w, cfg)
-            lanes.append(_Lane(w, cfg, code, _occupancy(w, cfg)))
-            idxs.append(i)
-        elif fallback:
-            try:
-                outcomes[i] = simulate(w, cfg)
-            except SimBudgetExceeded as e:
-                outcomes[i] = e
-        else:
-            raise ValueError(
-                f"config not batch-supported (scheduler={cfg.scheduler!r}, "
-                f"bank_model={cfg.bank_model!r}, trace={cfg.trace}, "
-                f"num_sms={cfg.num_sms})")
+    scalar: list[int] = []
+    with _phase("repro.sim.encode", "encode_s"):
+        for i, (w, cfg) in enumerate(jobs):
+            if batch_supported(cfg):
+                parse_interval_strategy(cfg.interval_strategy)  # as engine
+                code = _encode_plan(w, cfg)
+                lanes.append(_Lane(w, cfg, code, _occupancy(w, cfg)))
+                idxs.append(i)
+            elif fallback:
+                scalar.append(i)
+            else:
+                raise ValueError(
+                    f"config not batch-supported (scheduler={cfg.scheduler!r}"
+                    f", bank_model={cfg.bank_model!r}, trace={cfg.trace}, "
+                    f"num_sms={cfg.num_sms})")
+    for i in scalar:
+        w, cfg = jobs[i]
+        try:
+            outcomes[i] = simulate(w, cfg)
+        except SimBudgetExceeded as e:
+            outcomes[i] = e
     for chunk, chunk_idxs in _chunk_lanes(lanes, idxs):
         for i, r in zip(chunk_idxs, _run_lanes(chunk)):
             outcomes[i] = r

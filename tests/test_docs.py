@@ -217,3 +217,21 @@ def test_serving_doc_names_every_sweep_knob():
                  "quarantine", "sim_key", "SweepReport", "max_cycles",
                  "SimBudgetExceeded", "--chaos-smoke"):
         assert name in doc, f"{name} undocumented in serving.md"
+
+
+def test_host_and_device_names_documented():
+    """Every `RUN_STATS` key, `run_batch` phase span and model scope is on
+    docs/observability.md, so the program's names cannot drift from it."""
+    from repro.sim.batch import RUN_STATS
+
+    doc = (DOCS / "observability.md").read_text()
+    src = (ROOT / "src" / "repro" / "sim" / "batch.py").read_text()
+    spans = re.findall(r'_phase\("(repro\.sim\.\w+)"', src)
+    assert len(spans) == 4
+    models = "".join((ROOT / "src" / "repro" / d).read_text() for d in (
+        "models/lm.py", "models/layers.py", "optim/adamw.py"))
+    scopes = set(re.findall(r'named_scope\("(\w+)"\)', models))
+    assert scopes == {"embed", "attn", "mlp", "norm", "head_loss", "adamw"}
+    missing = [n for n in (*RUN_STATS, *spans, *scopes)
+               if f"`{n}`" not in doc]
+    assert not missing, missing

@@ -144,7 +144,9 @@ def test_run_stats_compile_run_split():
     cfg = design_config("LTRF", table2_config=7, num_warps=4)
     stats = B.reset_run_stats()
     assert stats == {"compile_s": 0.0, "run_s": 0.0,
-                     "compiles": 0, "launches": 0, "ticks": 0}
+                     "compiles": 0, "launches": 0, "ticks": 0,
+                     "encode_s": 0.0, "build_s": 0.0, "extract_s": 0.0,
+                     "lane_ticks": 0, "lane_slots": 0}
     res, = B.run_batch([(w, cfg)], fallback=False)
     assert stats["launches"] == 1
     assert stats["run_s"] > 0.0
