@@ -21,7 +21,8 @@ Phases (one chip):
 * ``serve``: eight requests through `repro.launch.serve.serve` at full
   width; all complete and no KV page leaks.
 * ``kernels``: the three Pallas kernels, compiled for the chip, against
-  their float32 oracles under ``highest`` matmul precision.
+  their float32 oracles under ``highest`` matmul precision (flash
+  attention's gradients too).
 
 ``--four-chips`` runs only the three training steps on a (data=2, model=2)
 mesh and the same steps on a 1x1 mesh on the first chip, and compares the
@@ -200,6 +201,17 @@ def phase_kernels(shapes=KERNEL_SHAPES, interpret: bool = False) -> dict:
             res.append({"kernel": "flash_attention", "shape": [B, H, KV, S, d],
                         **err(got, attention_ref(q, k, v),
                               KERNEL_TOL["flash_attention"])})
+            # the backward kernels: (dq, dk, dv) against the oracle's
+            do = rnd(9, (B, H, S, d))
+            grads = [jax.vjp(f, q, k, v)[1](do) for f in (
+                lambda *a: flash_attention(*a, interpret=interpret),
+                attention_ref)]
+            errs = [err(g, w, KERNEL_TOL["flash_attention"])
+                    for g, w in zip(*grads)]
+            res.append({"kernel": "flash_attention_grad",
+                        "shape": [B, H, KV, S, d],
+                        "max_abs_err": max(e["max_abs_err"] for e in errs),
+                        "ok": all(e["ok"] for e in errs)})
         for B, S, H, P, N, Q in shapes["ssd_scan"]:
             x = rnd(5, (B, S, H, P), 0.5)
             dt = jax.nn.softplus(rnd(6, (B, S, H)))

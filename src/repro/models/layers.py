@@ -7,6 +7,11 @@ tuples of logical axis names consumed by repro.distributed.sharding.
 The attention and MLP blocks run under the ``jax.named_scope``s ``attn``
 and ``mlp``: op metadata only, which a device trace reads back to put each
 operation (forward, remat recompute and backward alike) down to its block.
+
+Causal self-attention takes the fused flash kernel
+(``kernels/flash_attention``) where its preconditions hold and the XLA
+q-block scan (``causal_attention``) elsewhere; ``ATTN_STATS`` counts which
+path each call took on the platform the step was lowered for.
 """
 from __future__ import annotations
 
@@ -16,6 +21,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import Primitive
+from jax.interpreters import ad, batching, mlir
+
+from repro.distributed.sharding import active_rules
+from repro.kernels.flash_attention.kernel import causal_tiles
+from repro.kernels.flash_attention.ops import flash_attention
 
 
 def _init(key, shape, scale, dtype):
@@ -156,13 +167,109 @@ def causal_attention(q, k, v, q_block: int = 512, q_offset=None):
     return out.astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# path selection: the fused flash kernel where its preconditions hold
+# ---------------------------------------------------------------------------
+
+# Which path each self-attention call took, counted when a step is lowered
+# (once per lowering of each call site, not per execution): ``kernel_calls``
+# and ``xla_calls``, and the forward kernel's (query block, key block)
+# tiles over every batch row and head, run or skipped by causality.
+ATTN_STATS = {"kernel_calls": 0, "xla_calls": 0, "tiles_run": 0,
+              "tiles_skipped": 0}
+
+FLASH_BLOCKS = (512, 256, 128)
+
+
+def reset_attn_stats() -> dict:
+    for key in ATTN_STATS:
+        ATTN_STATS[key] = 0
+    return ATTN_STATS
+
+
+def _count_path(path, tiles_run, tiles_skipped):
+    ATTN_STATS[f"{path}_calls"] += 1
+    ATTN_STATS["tiles_run"] += tiles_run
+    ATTN_STATS["tiles_skipped"] += tiles_skipped
+
+
+# An identity on a branch's queries whose lowering counts the branch:
+# ``platform_dependent`` traces every branch, and only the lowering for a
+# platform keeps one of them.  The queries, not the output: a gradient that
+# drops the output still feeds the queries to the kernel.
+_attn_path_p = Primitive("attn_path")
+_attn_path_p.def_abstract_eval(lambda x, **_: x)
+_attn_path_p.def_impl(lambda x, **kw: (_count_path(**kw), x)[1])
+ad.primitive_jvps[_attn_path_p] = (
+    lambda primals, tangents, **kw: (_attn_path_p.bind(primals[0], **kw),
+                                     tangents[0]))
+batching.primitive_batchers[_attn_path_p] = (
+    lambda args, dims, **kw: (_attn_path_p.bind(args[0], **kw), dims[0]))
+
+
+def _attn_path_lowering(ctx, x, **kw):
+    _count_path(**kw)
+    return [x]
+
+
+mlir.register_lowering(_attn_path_p, _attn_path_lowering)
+
+
+def _tag(x, path, tiles_run=0, tiles_skipped=0):
+    return _attn_path_p.bind(x, path=path, tiles_run=tiles_run,
+                             tiles_skipped=tiles_skipped)
+
+
+def flash_block(seq: int, head_dim: int, n_heads: int, n_kv: int):
+    """The flash kernel's square tile for causal self-attention over
+    ``seq`` positions, or None where only the XLA path may run.
+
+    The kernel's blocks must tile the (8, 128) layout: the sequence and the
+    head width in multiples of 128.  GQA maps query head h to kv head
+    h // group, so the kv heads must divide the query heads.  The kernel is
+    not partitioned across devices, so the active sharding rules' mesh must
+    hold one device (or no rules are active)."""
+    rules = active_rules()
+    if (head_dim % 128 or n_heads % n_kv
+            or (rules is not None and rules.mesh.size > 1)):
+        return None
+    return next((b for b in FLASH_BLOCKS if seq % b == 0), None)
+
+
+def _xla_attention(q, k, v, q_block):
+    return causal_attention(_tag(q, "xla"), k, v, q_block=q_block)
+
+
+def _kernel_attention(q, k, v, block):
+    B, S, H, _ = q.shape
+    run, skipped = causal_tiles(S, block, block)
+    q = _tag(q, "kernel", B * H * run, B * H * skipped)
+    out = flash_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+                          bq=block, bk=block)
+    return out.transpose(0, 2, 1, 3)
+
+
+def self_attention(q, k, v, q_block: int = 512):
+    """Causal self-attention, q: (B,S,H,hd), k/v: (B,S,KV,hd).
+
+    On a TPU, the fused flash kernel where ``flash_block`` finds a tile;
+    elsewhere, and on other platforms, ``causal_attention``."""
+    _, S, H, hd = q.shape
+    block = flash_block(S, hd, H, k.shape[2])
+    xla = partial(_xla_attention, q_block=q_block)
+    if block is None:
+        return xla(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, tpu=partial(_kernel_attention, block=block), default=xla)
+
+
 @jax.named_scope("attn")
 def attention_block(params, x, *, n_heads, n_kv, head_dim, positions,
                     qk_norm=False, rope_theta=10000.0, norm_eps=1e-5,
                     q_block=512):
     q, k, v = _qkv(params, x, n_heads, n_kv, head_dim, positions, qk_norm,
                    rope_theta, norm_eps)
-    out = causal_attention(q, k, v, q_block=q_block)
+    out = self_attention(q, k, v, q_block=q_block)
     B, S, _, _ = out.shape
     return out.reshape(B, S, n_heads * head_dim) @ params["wo"]
 

@@ -220,8 +220,10 @@ def test_serving_doc_names_every_sweep_knob():
 
 
 def test_host_and_device_names_documented():
-    """Every `RUN_STATS` key, `run_batch` phase span and model scope is on
-    docs/observability.md, so the program's names cannot drift from it."""
+    """Every `RUN_STATS` key, `run_batch` phase span, model scope and
+    `ATTN_STATS` key is on docs/observability.md, so the program's names
+    cannot drift from it."""
+    from repro.models.layers import ATTN_STATS
     from repro.sim.batch import RUN_STATS
 
     doc = (DOCS / "observability.md").read_text()
@@ -232,6 +234,6 @@ def test_host_and_device_names_documented():
         "models/lm.py", "models/layers.py", "optim/adamw.py"))
     scopes = set(re.findall(r'named_scope\("(\w+)"\)', models))
     assert scopes == {"embed", "attn", "mlp", "norm", "head_loss", "adamw"}
-    missing = [n for n in (*RUN_STATS, *spans, *scopes)
+    missing = [n for n in (*RUN_STATS, *spans, *scopes, *ATTN_STATS)
                if f"`{n}`" not in doc]
     assert not missing, missing

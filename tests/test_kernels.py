@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.kernels.flash_attention.kernel import causal_tiles
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ltrf_matmul.ops import (
@@ -14,6 +15,7 @@ from repro.kernels.ltrf_matmul.ops import (
 from repro.kernels.ltrf_matmul.ref import matmul_ref
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_ref
+from repro.models.layers import causal_attention
 
 
 def _tol(dtype):
@@ -135,6 +137,55 @@ def test_flash_attention_rows_sum_to_one_property():
     got = flash_attention(q, k, v, bq=32, bk=32, interpret=True)
     np.testing.assert_allclose(np.asarray(got[0, 0, 0]), np.asarray(v[0, 0, 0]),
                                rtol=1e-4, atol=1e-4)
+
+
+def _bhsd(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+def _xla_path(q, k, v):
+    """The model's XLA q-block scan, in the kernel's (B, H, S, d) layout."""
+    return _bhsd(causal_attention(_bhsd(q), _bhsd(k), _bhsd(v), q_block=128))
+
+
+def _value_and_grads(fn, q, k, v, do):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return [np.asarray(x, np.float32) for x in (out, *vjp(do))]
+
+
+@pytest.mark.parametrize("S,block", [(256, 128), (512, 128), (256, 256),
+                                     (512, 256)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("H,KV", [(2, 2), (4, 2), (4, 1)],
+                         ids=["mha", "gqa2", "mqa"])
+def test_flash_attention_grad(H, KV, dtype, S, block):
+    """Forward and (dq, dk, dv) of the custom VJP against the naive oracle
+    and against the model's XLA path, at head width 128."""
+    d = 128
+    ks = jax.random.split(jax.random.PRNGKey(S + block + H * 7 + KV), 4)
+    q = jax.random.normal(ks[0], (1, H, S, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (1, KV, S, d)).astype(dtype)
+    v = jax.random.normal(ks[2], (1, KV, S, d)).astype(dtype)
+    do = jax.random.normal(ks[3], (1, H, S, d)).astype(dtype)
+    got = _value_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, bq=block, bk=block,
+                                        interpret=True), q, k, v, do)
+    for want_fn in (attention_ref, _xla_path):
+        want = _value_and_grads(want_fn, q, k, v, do)
+        for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+            assert g.shape == w.shape, name
+            np.testing.assert_allclose(g, w, err_msg=name, **_tol(dtype))
+
+
+@pytest.mark.parametrize("S,bq,bk,run,skipped", [
+    (256, 128, 128, 3, 1),         # n = 2 square tiles: n(n+1)/2 run
+    (512, 128, 128, 10, 6),        # n = 4
+    (512, 256, 256, 3, 1),
+    (4096, 512, 512, 36, 28),      # the train cell: 36 of 64 tiles run
+    (512, 128, 256, 6, 2),         # oblong: a tile runs if any pair is causal
+])
+def test_flash_attention_causal_tiles(S, bq, bk, run, skipped):
+    assert causal_tiles(S, bq, bk) == (run, skipped)
 
 
 # ---------------------------------------------------------------------------

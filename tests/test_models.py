@@ -177,3 +177,56 @@ def test_fp8_kv_cache_decode_close_to_bf16():
     # fp8 quantization noise is visible but bounded
     np.testing.assert_allclose(a, b, rtol=0.35, atol=0.6)
     assert np.corrcoef(a.ravel(), b.ravel())[0, 1] > 0.98
+
+
+@pytest.mark.parametrize("S,H,KV,hd,mesh,block", [
+    (4096, 16, 8, 128, None, 512),       # qwen3-0.6b at the train cell's length
+    (4096, 16, 8, 128, (1, 1), 512),     # the same under one-device rules
+    (384, 16, 8, 128, None, 128),        # the largest tile that divides S
+    (4096, 16, 8, 32, None, None),       # head width 32: the test configs
+    (4000, 16, 8, 128, None, None),      # S not a multiple of 128
+    (4096, 12, 8, 128, None, None),      # kv heads do not divide the heads
+    (4096, 16, 8, 128, (2, 2), None),    # a mesh of four devices
+], ids=["qwen3", "qwen3-rules", "s384", "hd32", "s4000", "gqa12-8", "mesh4"])
+def test_attention_path_selection(S, H, KV, hd, mesh, block):
+    """attention_block lowered for a TPU takes the flash kernel exactly where
+    its preconditions hold, and ATTN_STATS counts the path it lowered."""
+    from jax.sharding import AbstractMesh
+
+    from repro.distributed.sharding import ShardingRules, use_rules
+    from repro.kernels.flash_attention.kernel import causal_tiles
+    from repro.models import layers
+
+    d_model = 256
+    params = jax.eval_shape(lambda key: layers.init_attention(
+        key, d_model, H, KV, hd, True, jnp.bfloat16)[0], jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((1, S, d_model), jnp.bfloat16)
+    pos = jax.ShapeDtypeStruct((1, S), jnp.int32)
+    rules = None if mesh is None else ShardingRules(
+        mesh=AbstractMesh(mesh, ("data", "model")))
+
+    def fwd(p, x, pos):
+        return layers.attention_block(p, x, n_heads=H, n_kv=KV, head_dim=hd,
+                                      positions=pos, qk_norm=True)
+
+    with use_rules(rules):
+        assert layers.flash_block(S, hd, H, KV) == block
+        stats = dict(layers.reset_attn_stats())
+        assert stats == dict.fromkeys(stats, 0)
+        hlo = jax.jit(fwd).trace(params, x, pos).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert ("tpu_custom_call" in hlo) == (block is not None)
+    if block is None:
+        assert layers.ATTN_STATS == {"kernel_calls": 0, "xla_calls": 1,
+                                     "tiles_run": 0, "tiles_skipped": 0}
+    else:
+        run, skipped = causal_tiles(S, block, block)
+        assert layers.ATTN_STATS == {"kernel_calls": 1, "xla_calls": 0,
+                                     "tiles_run": H * run,
+                                     "tiles_skipped": H * skipped}
+    # lowered for the CPU, the same step keeps the XLA path
+    layers.reset_attn_stats()
+    with use_rules(rules):
+        jax.jit(fwd).trace(params, x, pos).lower(lowering_platforms=("cpu",))
+    assert (layers.ATTN_STATS["kernel_calls"],
+            layers.ATTN_STATS["xla_calls"]) == (0, 1)
