@@ -12,11 +12,13 @@ from dataclasses import dataclass, field, replace
 import jax
 import jax.numpy as jnp
 
+CONV_K = 4  # Mamba-2's depthwise conv width (models/mamba2.py)
+
 
 @dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                 # dense | moe | vlm | audio | ssm | hybrid
+    family: str                 # dense | moe | vlm | audio | ssm | hybrid | pattern
     n_layers: int
     d_model: int
     n_heads: int
@@ -36,8 +38,17 @@ class ArchConfig:
     ssm_headdim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
+    ssm_conv_bias: bool = False  # the depthwise conv adds a bias
     # hybrid (zamba2)
     attn_every: int = 0         # shared attention block period
+    # pattern: one mixer kind per layer, each mixer followed by its own MLP
+    layer_types: tuple = ()     # "mamba" | "attention" per layer
+    embedding_multiplier: float = 1.0   # embeddings scaled by this
+    residual_multiplier: float = 1.0    # each block's output, before its add
+    logits_scaling: float = 1.0         # logits divided by this
+    attention_multiplier: float = 0.0   # score scale; 0 -> head_dim ** -0.5
+    rope: bool = True           # False: no position embedding (NoPE)
+    tie_embeddings: bool = False  # the head is the embedding's transpose
     # frontends
     n_codebooks: int = 0        # musicgen: parallel EnCodec codebooks
     n_patches: int = 0          # llava: image patch positions (frontend stub)
@@ -62,6 +73,21 @@ class ArchConfig:
         return self.family == "ssm"
 
     @property
+    def score_scale(self) -> float | None:
+        """Attention's score scale, None for ``head_dim ** -0.5``."""
+        return self.attention_multiplier or None
+
+    def runs(self) -> list[tuple[str, int]]:
+        """``layer_types`` as maximal runs of one kind: [(kind, length)]."""
+        out: list[list] = []
+        for kind in self.layer_types:
+            if out and out[-1][0] == kind:
+                out[-1][1] += 1
+            else:
+                out.append([kind, 1])
+        return [(k, n) for k, n in out]
+
+    @property
     def sub_quadratic(self) -> bool:
         return self.family in ("ssm", "hybrid")
 
@@ -74,6 +100,12 @@ class ArchConfig:
             n = self.n_codebooks * V * D
         attn = D * H * hd + 2 * D * KV * hd + H * hd * D
         mlp = 3 * D * F
+        if self.layer_types:
+            n_mamba = self.layer_types.count("mamba")
+            n += n_mamba * self._mamba_params()
+            n += (len(self.layer_types) - n_mamba) * attn
+            n += len(self.layer_types) * (mlp + 2 * D) + D
+            return n if self.tie_embeddings else n + D * V
         if self.family == "moe":
             per_layer = attn + self.n_experts * mlp + D * self.n_experts + 2 * D
             n += L * per_layer
@@ -93,8 +125,8 @@ class ArchConfig:
         d_inner = self.ssm_expand * D
         nheads = d_inner // self.ssm_headdim
         d_in_proj = 2 * d_inner + 2 * self.ssm_state + nheads
-        return (D * d_in_proj + 4 * (d_inner + 2 * self.ssm_state)
-                + 3 * nheads + d_inner + d_inner * D)
+        conv = (CONV_K + self.ssm_conv_bias) * (d_inner + 2 * self.ssm_state)
+        return D * d_in_proj + conv + 3 * nheads + d_inner + d_inner * D
 
     def active_param_count(self) -> int:
         """MoE: params touched per token (top-k experts only)."""

@@ -16,6 +16,7 @@ ARCH_IDS = [
     "musicgen-large",
     "mamba2-1.3b",
     "zamba2-1.2b",
+    "granite-4.0-h-micro",
 ]
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}
@@ -32,7 +33,7 @@ def get_smoke(arch_id: str) -> ArchConfig:
 
 
 def all_cells() -> list[tuple[str, str, bool, str]]:
-    """[(arch_id, shape_name, runnable, skip_reason)] for all 40 cells."""
+    """[(arch_id, shape_name, runnable, skip_reason)] for every cell."""
     out = []
     for a in ARCH_IDS:
         cfg = get_arch(a)

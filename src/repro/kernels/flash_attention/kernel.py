@@ -139,15 +139,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
 
 def flash_forward(q, k, v, *, group: int, bq: int, bk: int,
-                  causal: bool = True, interpret: bool = False):
-    """q: (BH, S, d); k/v: (BKV, S, d) with BH = BKV * group.
+                  causal: bool = True, interpret: bool = False,
+                  scale: float | None = None):
+    """q: (BH, S, d); k/v: (BKV, S, d) with BH = BKV * group; scores
+    scaled by ``scale``, ``d ** -0.5`` when None.
 
     Returns ``o`` (BH, S, d) in q's dtype and the per-row log-sum-exp of
     the scaled scores, (BH, 1, S) float32."""
     BH, S, d = q.shape
     assert S % bq == 0 and S % bk == 0, (S, bq, bk)
     n_k = S // bk
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
 
     def kv_map(bh, qi, ki):
         if causal:
@@ -255,14 +257,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
 
 
 def flash_backward(q, k, v, o, lse, do, *, group: int, bq: int, bk: int,
-                   causal: bool = True, interpret: bool = False):
+                   causal: bool = True, interpret: bool = False,
+                   scale: float | None = None):
     """Gradients (dq, dk, dv) of ``flash_forward``'s ``o`` given ``do``.
 
-    Shapes as ``flash_forward``; ``lse`` is its (BH, 1, S) output."""
+    Shapes and ``scale`` as ``flash_forward``; ``lse`` is its (BH, 1, S)
+    output."""
     BH, S, d = q.shape
     BKV = k.shape[0]
     n_q, n_k = S // bq, S // bk
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
                  axis=-1)[:, None, :]                          # (BH, 1, S)
     params = pltpu.CompilerParams(

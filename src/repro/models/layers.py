@@ -11,7 +11,8 @@ operation (forward, remat recompute and backward alike) down to its block.
 Causal self-attention takes the fused flash kernel
 (``kernels/flash_attention``) where its preconditions hold and the XLA
 q-block scan (``causal_attention``) elsewhere; ``ATTN_STATS`` counts which
-path each call took on the platform the step was lowered for.
+path each call took on the platform the step was lowered for
+(`repro.models.paths`).
 """
 from __future__ import annotations
 
@@ -21,12 +22,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.extend.core import Primitive
-from jax.interpreters import ad, batching, mlir
 
 from repro.distributed.sharding import active_rules
 from repro.kernels.flash_attention.kernel import causal_tiles
 from repro.kernels.flash_attention.ops import flash_attention
+
+from . import paths
 
 
 def _init(key, shape, scale, dtype):
@@ -96,7 +97,7 @@ def init_attention(key, d_model, n_heads, n_kv, head_dim, qk_norm, dtype):
 
 
 def _qkv(params, x, cfg_heads, cfg_kv, head_dim, positions, qk_norm, rope_theta,
-         norm_eps):
+         norm_eps, rope=True):
     B, S, _ = x.shape
     q = (x @ params["wq"]).reshape(B, S, cfg_heads, head_dim)
     k = (x @ params["wk"]).reshape(B, S, cfg_kv, head_dim)
@@ -104,8 +105,9 @@ def _qkv(params, x, cfg_heads, cfg_kv, head_dim, positions, qk_norm, rope_theta,
     if qk_norm:
         q = rms_norm(q, params["q_norm"], norm_eps)
         k = rms_norm(k, params["k_norm"], norm_eps)
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    if rope:
+        q = apply_rope(q, positions, rope_theta)
+        k = apply_rope(k, positions, rope_theta)
     return q, k, v
 
 
@@ -120,18 +122,19 @@ def _repeat_kv(k, n_heads):
     return jnp.repeat(k, rep, axis=2)
 
 
-def causal_attention(q, k, v, q_block: int = 512, q_offset=None):
+def causal_attention(q, k, v, q_block: int = 512, q_offset=None, scale=None):
     """Memory-efficient causal attention.
 
     q: (B,Sq,H,hd), k/v: (B,Skv,KV,hd).  Scans over q blocks so peak memory is
     O(Sq_block x Skv) rather than O(Sq x Skv).  ``q_offset`` shifts query
-    positions (for decode, q_offset = Skv - Sq).
+    positions (for decode, q_offset = Skv - Sq).  Scores are scaled by
+    ``scale``, ``hd ** -0.5`` when None.
     """
     B, Sq, H, hd = q.shape
     Skv = k.shape[1]
     k = _repeat_kv(k, H)
     v = _repeat_kv(v, H)
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     offset = Skv - Sq if q_offset is None else q_offset
 
     kT = k.transpose(0, 2, 3, 1)  # (B,H,hd,Skv)
@@ -171,125 +174,100 @@ def causal_attention(q, k, v, q_block: int = 512, q_offset=None):
 # path selection: the fused flash kernel where its preconditions hold
 # ---------------------------------------------------------------------------
 
-# Which path each self-attention call took, counted when a step is lowered
-# (once per lowering of each call site, not per execution): ``kernel_calls``
-# and ``xla_calls``, and the forward kernel's (query block, key block)
-# tiles over every batch row and head, run or skipped by causality.
-ATTN_STATS = {"kernel_calls": 0, "xla_calls": 0, "tiles_run": 0,
-              "tiles_skipped": 0}
+# Which path each self-attention call took (`repro.models.paths`):
+# ``kernel_calls`` and ``xla_calls``, and the forward kernel's (query block,
+# key block) tiles over every batch row and head, run or skipped by
+# causality.
+ATTN_STATS = paths.counter("attn", "tiles_run", "tiles_skipped")
 
 FLASH_BLOCKS = (512, 256, 128)
 
 
 def reset_attn_stats() -> dict:
-    for key in ATTN_STATS:
-        ATTN_STATS[key] = 0
-    return ATTN_STATS
-
-
-def _count_path(path, tiles_run, tiles_skipped):
-    ATTN_STATS[f"{path}_calls"] += 1
-    ATTN_STATS["tiles_run"] += tiles_run
-    ATTN_STATS["tiles_skipped"] += tiles_skipped
-
-
-# An identity on a branch's queries whose lowering counts the branch:
-# ``platform_dependent`` traces every branch, and only the lowering for a
-# platform keeps one of them.  The queries, not the output: a gradient that
-# drops the output still feeds the queries to the kernel.
-_attn_path_p = Primitive("attn_path")
-_attn_path_p.def_abstract_eval(lambda x, **_: x)
-_attn_path_p.def_impl(lambda x, **kw: (_count_path(**kw), x)[1])
-ad.primitive_jvps[_attn_path_p] = (
-    lambda primals, tangents, **kw: (_attn_path_p.bind(primals[0], **kw),
-                                     tangents[0]))
-batching.primitive_batchers[_attn_path_p] = (
-    lambda args, dims, **kw: (_attn_path_p.bind(args[0], **kw), dims[0]))
-
-
-def _attn_path_lowering(ctx, x, **kw):
-    _count_path(**kw)
-    return [x]
-
-
-mlir.register_lowering(_attn_path_p, _attn_path_lowering)
-
-
-def _tag(x, path, tiles_run=0, tiles_skipped=0):
-    return _attn_path_p.bind(x, path=path, tiles_run=tiles_run,
-                             tiles_skipped=tiles_skipped)
+    return paths.reset(ATTN_STATS)
 
 
 def flash_block(seq: int, head_dim: int, n_heads: int, n_kv: int):
     """The flash kernel's square tile for causal self-attention over
     ``seq`` positions, or None where only the XLA path may run.
 
-    The kernel's blocks must tile the (8, 128) layout: the sequence and the
-    head width in multiples of 128.  GQA maps query head h to kv head
+    The kernel's blocks must tile the (8, 128) layout: the sequence in
+    multiples of 128, and the head width too, or 64, where a block spans
+    the whole last dimension (its lane-broadcast row statistics take a
+    width under 128 whole).  GQA maps query head h to kv head
     h // group, so the kv heads must divide the query heads.  The kernel is
     not partitioned across devices, so the active sharding rules' mesh must
     hold one device (or no rules are active)."""
     rules = active_rules()
-    if (head_dim % 128 or n_heads % n_kv
+    if ((head_dim % 128 and head_dim != 64) or n_heads % n_kv
             or (rules is not None and rules.mesh.size > 1)):
         return None
     return next((b for b in FLASH_BLOCKS if seq % b == 0), None)
 
 
-def _xla_attention(q, k, v, q_block):
-    return causal_attention(_tag(q, "xla"), k, v, q_block=q_block)
+def _xla_attention(q, k, v, q_block, scale):
+    return causal_attention(paths.tag(q, "attn", "xla"), k, v,
+                            q_block=q_block, scale=scale)
 
 
-def _kernel_attention(q, k, v, block):
+def _kernel_attention(q, k, v, block, scale):
     B, S, H, _ = q.shape
     run, skipped = causal_tiles(S, block, block)
-    q = _tag(q, "kernel", B * H * run, B * H * skipped)
+    q = paths.tag(q, "attn", "kernel", tiles_run=B * H * run,
+                  tiles_skipped=B * H * skipped)
     out = flash_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
-                          bq=block, bk=block)
+                          bq=block, bk=block, scale=scale)
     return out.transpose(0, 2, 1, 3)
 
 
-def self_attention(q, k, v, q_block: int = 512):
-    """Causal self-attention, q: (B,S,H,hd), k/v: (B,S,KV,hd).
+def self_attention(q, k, v, q_block: int = 512, scale=None):
+    """Causal self-attention, q: (B,S,H,hd), k/v: (B,S,KV,hd), scores
+    scaled by ``scale`` (``hd ** -0.5`` when None).
 
     On a TPU, the fused flash kernel where ``flash_block`` finds a tile;
     elsewhere, and on other platforms, ``causal_attention``."""
     _, S, H, hd = q.shape
     block = flash_block(S, hd, H, k.shape[2])
-    xla = partial(_xla_attention, q_block=q_block)
+    xla = partial(_xla_attention, q_block=q_block, scale=scale)
     if block is None:
         return xla(q, k, v)
     return jax.lax.platform_dependent(
-        q, k, v, tpu=partial(_kernel_attention, block=block), default=xla)
+        q, k, v, tpu=partial(_kernel_attention, block=block, scale=scale),
+        default=xla)
 
 
 @jax.named_scope("attn")
 def attention_block(params, x, *, n_heads, n_kv, head_dim, positions,
                     qk_norm=False, rope_theta=10000.0, norm_eps=1e-5,
-                    q_block=512):
+                    q_block=512, rope=True, scale=None):
+    """Causal GQA self-attention over ``x``: (B, S, D).  ``rope`` off leaves
+    the positions out (no position embedding); ``scale`` multiplies the
+    scores, ``head_dim ** -0.5`` when None."""
     q, k, v = _qkv(params, x, n_heads, n_kv, head_dim, positions, qk_norm,
-                   rope_theta, norm_eps)
-    out = self_attention(q, k, v, q_block=q_block)
+                   rope_theta, norm_eps, rope)
+    out = self_attention(q, k, v, q_block=q_block, scale=scale)
     B, S, _, _ = out.shape
     return out.reshape(B, S, n_heads * head_dim) @ params["wo"]
 
 
 def attention_decode(params, x, cache_k, cache_v, cache_len, *, n_heads, n_kv,
-                     head_dim, qk_norm=False, rope_theta=10000.0, norm_eps=1e-5):
-    """One-token decode against a (B, S_max, kv, hd) KV cache.
+                     head_dim, qk_norm=False, rope_theta=10000.0, norm_eps=1e-5,
+                     rope=True, scale=None):
+    """One-token decode against a (B, S_max, kv, hd) KV cache; ``rope`` and
+    ``scale`` as in `attention_block`.
 
     Returns (out, new_cache_k, new_cache_v).
     """
     B, S, _ = x.shape  # S == 1
     positions = jnp.full((B, S), cache_len, dtype=jnp.int32)
     q, k, v = _qkv(params, x, n_heads, n_kv, head_dim, positions, qk_norm,
-                   rope_theta, norm_eps)
+                   rope_theta, norm_eps, rope)
     cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), cache_len, axis=1)
     cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), cache_len, axis=1)
     S_max = cache_k.shape[1]
     kk = _repeat_kv(cache_k, n_heads)
     vv = _repeat_kv(cache_v, n_heads)
-    scale = 1.0 / math.sqrt(head_dim)
+    scale = 1.0 / math.sqrt(head_dim) if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kk.astype(jnp.float32)) * scale
     mask = jnp.arange(S_max)[None, :] <= cache_len  # current token included
     logits = jnp.where(mask[None, None], logits, -1e30)

@@ -6,10 +6,20 @@ axis) so the lowered HLO stays small enough to compile 512-device meshes on
 one CPU host.  Activation/param logical-axis annotations flow through
 `repro.distributed.sharding.constrain`.
 
+A configuration with ``layer_types`` (family ``pattern``) is a per-layer
+pattern of mixer kinds instead: each layer is ``x += r * mixer(norm(x))``
+then ``x += r * mlp(norm(x))``, the mixer Mamba-2 (``"mamba"``) or causal
+GQA (``"attention"``).  Each maximal run of one kind is one parameter tree
+stacked over its layers (``params["layers"]``, a list in pattern order) and
+one ``lax.scan``; the decode cache holds conv and SSM state for a Mamba-2
+run and K/V for an attention run, in the same list.  Such a configuration
+is always scanned (``scan_layers`` does not apply).
+
 The training step's operations carry fixed ``jax.named_scope`` names,
-shared by every family: ``embed``, ``attn``, ``mlp`` (the MoE block too),
-``norm`` and ``head_loss`` here and in `layers`, ``adamw`` in the
-optimizer.  They are op metadata only; a device trace reads them back.
+shared by every family: ``embed``, ``attn``, ``ssm`` (the Mamba-2 mixer),
+``mlp`` (the MoE block too), ``norm`` and ``head_loss`` here, in `layers`
+and in `mamba2`, ``adamw`` in the optimizer.  They are op metadata only; a
+device trace reads them back.
 
 Entry points:
   init_params(cfg, key)            -> (params, logical_axes)
@@ -70,11 +80,52 @@ def _init_block(cfg: ArchConfig, key):
     return params, axes
 
 
+def _init_pattern_layer(cfg: ArchConfig, kind: str, key):
+    """One layer of a pattern: its mixer, MLP and two norms."""
+    ks = jax.random.split(key, 2)
+    dt = cfg.jdtype
+    if kind == "mamba":
+        mixer = init_mamba2(ks[0], cfg.d_model, cfg.ssm_state, cfg.ssm_headdim,
+                            cfg.ssm_expand, dt, cfg.ssm_conv_bias)
+    elif kind == "attention":
+        mixer = init_attention(ks[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+                               cfg.hd, cfg.qk_norm, dt)
+    else:
+        raise ValueError(f"unknown layer kind {kind!r}")
+    mlp = init_mlp(ks[1], cfg.d_model, cfg.d_ff, dt)
+    n1, n2 = init_rms(cfg.d_model), init_rms(cfg.d_model)
+    parts = {"mixer": mixer, "mlp": mlp, "norm1": n1, "norm2": n2}
+    return ({k: v[0] for k, v in parts.items()},
+            {k: v[1] for k, v in parts.items()})
+
+
+def _init_pattern(cfg: ArchConfig, key):
+    """Per run of ``cfg.runs()``, its layers' parameters stacked."""
+    keys = iter(jax.random.split(key, cfg.n_layers))
+    params, axes = [], []
+    for kind, n in cfg.runs():
+        layers = [_init_pattern_layer(cfg, kind, next(keys)) for _ in range(n)]
+        params.append(_stack([p for p, _ in layers]))
+        axes.append(stack_axes(layers[0][1]))
+    return params, axes
+
+
 def init_params(cfg: ArchConfig, key):
     ks = jax.random.split(key, 8)
     dt = cfg.jdtype
     params: dict = {}
     axes: dict = {}
+
+    if cfg.layer_types:
+        params["embed"], axes["embed"] = init_embedding(ks[0], cfg.vocab,
+                                                        cfg.d_model, dt)
+        if not cfg.tie_embeddings:
+            params["lm_head"] = _init(ks[1], (cfg.d_model, cfg.vocab),
+                                      1.0 / math.sqrt(cfg.d_model), dt)
+            axes["lm_head"] = ("embed", "vocab")
+        params["layers"], axes["layers"] = _init_pattern(cfg, ks[2])
+        params["final_norm"], axes["final_norm"] = init_rms(cfg.d_model)
+        return params, axes
 
     if cfg.family == "audio":
         K = cfg.n_codebooks
@@ -166,6 +217,28 @@ def _shared_block(cfg: ArchConfig, p, x, positions):
     return constrain(x, ("act_batch", "act_seq", "act_embed"))
 
 
+def _pattern_layer(cfg: ArchConfig, kind: str, positions, x, p):
+    """One layer of a pattern: the mixer, then the MLP, each on its own
+    norm and added back scaled by ``residual_multiplier``."""
+    r = cfg.residual_multiplier
+    h = _norm(x, p["norm1"], cfg.norm_eps)
+    if kind == "mamba":
+        h = mamba2_block(p["mixer"], h, d_state=cfg.ssm_state,
+                         headdim=cfg.ssm_headdim, expand=cfg.ssm_expand,
+                         chunk=cfg.ssm_chunk, norm_eps=cfg.norm_eps)
+    else:
+        h = attention_block(p["mixer"], h, n_heads=cfg.n_heads,
+                            n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
+                            positions=positions, qk_norm=cfg.qk_norm,
+                            rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
+                            q_block=cfg.q_block, rope=cfg.rope,
+                            scale=cfg.score_scale)
+    x = constrain(x + r * h, ("act_batch", "act_seq", "act_embed"))
+    h = _norm(x, p["norm2"], cfg.norm_eps)
+    x = x + r * mlp_block(p["mlp"], h)
+    return constrain(x, ("act_batch", "act_seq", "act_embed"))
+
+
 def _maybe_remat(fn, cfg: ArchConfig):
     if cfg.remat == "none":
         return fn
@@ -184,6 +257,13 @@ def _layer_slice(layers, i):
 
 def forward(params, cfg: ArchConfig, x, positions):
     """Backbone over embedded inputs x: (B, S, D) -> (B, S, D)."""
+    if cfg.layer_types:
+        for (kind, _), run in zip(cfg.runs(), params["layers"]):
+            blk = _maybe_remat(partial(_pattern_layer, cfg, kind, positions),
+                               cfg)
+            x, _ = jax.lax.scan(lambda xx, p: (blk(xx, p), None), x, run)
+        return (_norm(x, params["final_norm"], cfg.norm_eps),
+                jnp.zeros((), jnp.float32))
     if not cfg.scan_layers:
         return _forward_unrolled(params, cfg, x, positions)
     if cfg.family in ("dense", "moe", "vlm", "audio"):
@@ -266,10 +346,24 @@ def embed_inputs(params, cfg: ArchConfig, batch):
         x = sum(embed(params["embed"][k], batch["codes"][:, k]) for k in range(K))
     else:
         x = embed(params["embed"], batch["tokens"])
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     B, S = x.shape[0], x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     x = constrain(x, ("act_batch", "act_seq", "act_embed"))
     return x, positions
+
+
+def _logits(params, cfg: ArchConfig, h):
+    """The LM head's logits of ``h``: through the embedding's transpose
+    where the head is tied, divided by ``logits_scaling``.  The division
+    scales ``h``, a vocabulary-wide product's narrower side; by a power of
+    two, as Granite's 8, it is exact."""
+    if cfg.logits_scaling != 1.0:
+        h = h * (1.0 / cfg.logits_scaling)
+    if cfg.tie_embeddings:
+        return h @ params["embed"].T
+    return h @ params["lm_head"]
 
 
 @jax.named_scope("head_loss")
@@ -281,7 +375,7 @@ def _head_loss(params, cfg: ArchConfig, h, labels):
         logits = logits[:, :-1]
         lbl = labels[:, :, 1:].transpose(0, 2, 1)  # (B,S-1,K)
         return cross_entropy(logits, lbl)
-    logits = h @ params["lm_head"]
+    logits = _logits(params, cfg, h)
     logits = constrain(logits, ("act_batch", "act_seq", "act_vocab"))
     return cross_entropy(logits[:, :-1], labels[:, 1:])
 
@@ -304,6 +398,8 @@ def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int):
     dt = cfg.jdtype
     kv_dt = getattr(jnp, cfg.kv_dtype) if cfg.kv_dtype else dt
     L = cfg.n_layers
+    if cfg.layer_types:
+        return _pattern_cache(cfg, batch, max_len, dt, kv_dt)
     if cfg.family == "ssm" or cfg.family == "hybrid":
         d_inner = cfg.ssm_expand * cfg.d_model
         nheads = d_inner // cfg.ssm_headdim
@@ -332,10 +428,80 @@ def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int):
     return cache, axes
 
 
+def _pattern_cache(cfg: ArchConfig, batch: int, max_len: int, dt, kv_dt):
+    """Per run of ``cfg.runs()``: a Mamba-2 run's conv window and float32
+    SSM state, or an attention run's K and V, stacked over its layers."""
+    d_inner = cfg.ssm_expand * cfg.d_model
+    nheads = d_inner // cfg.ssm_headdim
+    caches, axes = [], []
+    for kind, n in cfg.runs():
+        if kind == "mamba":
+            caches.append({
+                "conv": jnp.zeros((n, batch, CONV_K - 1,
+                                   d_inner + 2 * cfg.ssm_state), dt),
+                "ssm": jnp.zeros((n, batch, nheads, cfg.ssm_headdim,
+                                  cfg.ssm_state), jnp.float32)})
+            axes.append({"conv": ("layers", "act_batch", None, "act_ffn"),
+                         "ssm": ("layers", "act_batch", None, None, None)})
+        else:
+            kv = ("layers", "act_batch", None, "act_kv", "act_hd")
+            caches.append({
+                "k": jnp.zeros((n, batch, max_len, cfg.n_kv_heads, cfg.hd),
+                               kv_dt),
+                "v": jnp.zeros((n, batch, max_len, cfg.n_kv_heads, cfg.hd),
+                               kv_dt)})
+            axes.append({"k": kv, "v": kv})
+    return {"layers": caches}, {"layers": axes}
+
+
+def _pattern_decode(params, cache, x, cache_len, cfg: ArchConfig):
+    """One token through a pattern's runs, each run's cache scanned with
+    its layers.  Returns (x, new cache)."""
+    r = cfg.residual_multiplier
+
+    def mlp(xx, p):
+        return xx + r * mlp_block(p["mlp"], rms_norm(xx, p["norm2"],
+                                                     cfg.norm_eps))
+
+    def mamba(xx, layer):
+        p, conv, ssm = layer
+        h = rms_norm(xx, p["norm1"], cfg.norm_eps)
+        h, new = mamba2_decode(p["mixer"], h, {"conv": conv, "ssm": ssm},
+                               d_state=cfg.ssm_state, headdim=cfg.ssm_headdim,
+                               expand=cfg.ssm_expand, norm_eps=cfg.norm_eps)
+        return mlp(xx + r * h, p), (new["conv"], new["ssm"])
+
+    def attention(xx, layer):
+        p, ck, cv = layer
+        h = rms_norm(xx, p["norm1"], cfg.norm_eps)
+        h, ck, cv = attention_decode(
+            p["mixer"], h, ck, cv, cache_len, n_heads=cfg.n_heads,
+            n_kv=cfg.n_kv_heads, head_dim=cfg.hd, qk_norm=cfg.qk_norm,
+            rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps, rope=cfg.rope,
+            scale=cfg.score_scale)
+        return mlp(xx + r * h, p), (ck, cv)
+
+    new = []
+    for (kind, _), run, c in zip(cfg.runs(), params["layers"],
+                                 cache["layers"]):
+        keys = ("conv", "ssm") if kind == "mamba" else ("k", "v")
+        x, out = jax.lax.scan(mamba if kind == "mamba" else attention, x,
+                              (run, *(c[k] for k in keys)))
+        new.append(dict(zip(keys, out)))
+    return x, {"layers": new}
+
+
 def decode_step(params, cache, tokens, cache_len, cfg: ArchConfig):
     """One-token decode.  tokens: (B,1) int32 (audio: (B,K,1)).
 
     Returns (logits, new_cache)."""
+    if cfg.layer_types:
+        x = embed(params["embed"], tokens)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
+        x, new_cache = _pattern_decode(params, cache, x, cache_len, cfg)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return _logits(params, cfg, x), new_cache
     if cfg.family == "audio":
         K = cfg.n_codebooks
         x = sum(embed(params["embed"][k], tokens[:, k]) for k in range(K))

@@ -5,6 +5,13 @@ the output is computed with dense matmuls (MXU-friendly), while chunk-final
 states are carried by an associative `lax.scan` — this is the structure the
 `kernels/ssd_scan` Pallas kernel accelerates.
 
+`ssd` is the one entry point a block calls.  On one TPU it runs the
+kernel's forward under a custom VJP (`kernels.ssd_scan.ops.ssd`); elsewhere,
+and where the active sharding rules' mesh holds more than one device,
+`ssd_chunked` in XLA.  ``SSD_STATS`` counts the path each call took on the
+platform the step was lowered for (`repro.models.paths`).  The mixer runs
+under the ``jax.named_scope`` ``ssm``.
+
 Shapes follow the minimal Mamba2 formulation with n_groups=1:
   x:  (B, S, H, P)    per-head inputs (P = head dim)
   dt: (B, S, H)       softplus-positive step sizes
@@ -15,16 +22,28 @@ State: (B, H, P, N).
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from repro.configs.base import CONV_K  # depthwise conv kernel width
+from repro.distributed.sharding import active_rules
+from repro.kernels.ssd_scan.ops import ssd as ssd_kernel
+
+from . import paths
 from .layers import _init, rms_norm
 
-CONV_K = 4  # depthwise conv kernel width
+# Which path each SSD call took: ``kernel_calls`` and ``xla_calls``.
+SSD_STATS = paths.counter("ssd")
 
 
-def init_mamba2(key, d_model, d_state, headdim, expand, dtype):
+def reset_ssd_stats() -> dict:
+    return paths.reset(SSD_STATS)
+
+
+def init_mamba2(key, d_model, d_state, headdim, expand, dtype,
+                conv_bias: bool = False):
     d_inner = expand * d_model
     nheads = d_inner // headdim
     ks = jax.random.split(key, 5)
@@ -48,6 +67,9 @@ def init_mamba2(key, d_model, d_state, headdim, expand, dtype):
         "norm": ("ffn",),
         "out_proj": ("ffn", "embed"),
     }
+    if conv_bias:
+        params["conv_bias"] = jnp.zeros((d_inner + 2 * d_state,), dtype)
+        axes["conv_bias"] = ("ffn",)
     return params, axes
 
 
@@ -58,8 +80,9 @@ def _split_proj(zxbcdt, d_inner, d_state):
     return z, xBC, dt
 
 
-def _causal_conv(xBC, conv_w, state=None):
-    """Depthwise causal conv along seq.  xBC: (B,S,C); conv_w: (K,C).
+def _causal_conv(xBC, conv_w, state=None, bias=None):
+    """Depthwise causal conv along seq.  xBC: (B,S,C); conv_w: (K,C);
+    ``bias`` (C,) or None.
 
     With ``state`` (B, K-1, C) performs streaming conv (decode)."""
     B, S, C = xBC.shape
@@ -70,6 +93,8 @@ def _causal_conv(xBC, conv_w, state=None):
         xBC = jnp.pad(xBC, ((0, 0), (CONV_K - 1, 0), (0, 0)))
         new_state = xBC[:, -(CONV_K - 1):]
     out = sum(xBC[:, k:k + S] * conv_w[k][None, None] for k in range(CONV_K))
+    if bias is not None:
+        out = out + bias
     return jax.nn.silu(out), new_state
 
 
@@ -155,8 +180,38 @@ def ssd_decode_step(state, x, dt, A, Bm, Cm):
     return y, new_state
 
 
+def ssd_on_kernel(seq: int, chunk: int) -> bool:
+    """Whether an SSD over ``seq`` positions may take the chunk kernel on a
+    TPU: the sequence in whole chunks, and at most one device in the active
+    rules' mesh (the kernel is not partitioned across devices)."""
+    rules = active_rules()
+    return (seq % min(chunk, seq) == 0
+            and (rules is None or rules.mesh.size <= 1))
+
+
+def _xla_ssd(x, dt, A, Bm, Cm, chunk):
+    return ssd_chunked(paths.tag(x, "ssd", "xla"), dt, A, Bm, Cm, chunk)[0]
+
+
+def _kernel_ssd(x, dt, A, Bm, Cm, chunk):
+    return ssd_kernel(paths.tag(x, "ssd", "kernel"), dt, A, Bm, Cm,
+                      chunk=min(chunk, x.shape[1]))
+
+
+def ssd(x, dt, A, Bm, Cm, chunk: int):
+    """The SSD's outputs y: (B,S,H,P) over a whole sequence, arguments as
+    `ssd_chunked`: the chunk kernel on one TPU where `ssd_on_kernel`, else
+    `ssd_chunked`."""
+    xla = partial(_xla_ssd, chunk=chunk)
+    if not ssd_on_kernel(x.shape[1], chunk):
+        return xla(x, dt, A, Bm, Cm)
+    return jax.lax.platform_dependent(
+        x, dt, A, Bm, Cm, tpu=partial(_kernel_ssd, chunk=chunk), default=xla)
+
+
+@jax.named_scope("ssm")
 def mamba2_block(params, x, *, d_state, headdim, expand, chunk,
-                 norm_eps=1e-5, initial=None, return_state=False):
+                 norm_eps=1e-5):
     """Full Mamba2 mixer over a sequence.  x: (B,S,D)."""
     B, S, D = x.shape
     d_inner = expand * D
@@ -164,30 +219,17 @@ def mamba2_block(params, x, *, d_state, headdim, expand, chunk,
     zxbcdt = x @ params["in_proj"]
     z, xBC, dt = _split_proj(zxbcdt, d_inner, d_state)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    conv_state = None if initial is None else initial.get("conv")
-    xBC, new_conv = _causal_conv(xBC, params["conv"], conv_state)
+    xBC, _ = _causal_conv(xBC, params["conv"], bias=params.get("conv_bias"))
     xs = xBC[..., :d_inner].reshape(B, S, nheads, headdim)
     Bm = xBC[..., d_inner:d_inner + d_state]
     Cm = xBC[..., d_inner + d_state:]
     A = -jnp.exp(params["A_log"])
-    ssm_state = None if initial is None else initial.get("ssm")
-    y, final = ssd_chunked(xs.astype(jnp.float32), dt, A,
-                           Bm.astype(jnp.float32), Cm.astype(jnp.float32), chunk)
-    if ssm_state is not None:
-        # carry-in state contribution (decode prefill continuation): add
-        # C_t . (decay from t=0) h_in
-        cumdA = jnp.cumsum(dt * A[None, None, :], axis=1)
-        dec = jnp.exp(jnp.clip(cumdA, -60.0, 0.0))
-        y = y + jnp.einsum("bsn,bhpn,bsh->bshp", Cm.astype(jnp.float32),
-                           ssm_state.astype(jnp.float32), dec)
-        final = final + ssm_state * jnp.exp(jnp.clip(cumdA[:, -1], -60.0, 0.0))[..., None, None]
+    y = ssd(xs.astype(jnp.float32), dt, A, Bm.astype(jnp.float32),
+            Cm.astype(jnp.float32), chunk)
     y = y + xs.astype(jnp.float32) * params["D"][None, None, :, None]
     y = y.reshape(B, S, d_inner).astype(x.dtype)
     y = rms_norm(y * jax.nn.silu(z), params["norm"], norm_eps)
-    out = y @ params["out_proj"]
-    if return_state:
-        return out, {"conv": new_conv, "ssm": final}
-    return out
+    return y @ params["out_proj"]
 
 
 def mamba2_decode(params, x, cache, *, d_state, headdim, expand, norm_eps=1e-5):
@@ -198,7 +240,8 @@ def mamba2_decode(params, x, cache, *, d_state, headdim, expand, norm_eps=1e-5):
     zxbcdt = x @ params["in_proj"]
     z, xBC, dt = _split_proj(zxbcdt, d_inner, d_state)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])[:, 0]  # (B,H)
-    xBC, new_conv = _causal_conv(xBC, params["conv"], cache["conv"])
+    xBC, new_conv = _causal_conv(xBC, params["conv"], cache["conv"],
+                                 params.get("conv_bias"))
     xs = xBC[:, 0, :d_inner].reshape(B, nheads, headdim)
     Bm = xBC[:, 0, d_inner:d_inner + d_state]
     Cm = xBC[:, 0, d_inner + d_state:]

@@ -119,6 +119,13 @@ def _qwen3_train_step(topo, batch_rows: int, seq: int):
     """The full-width, full-depth qwen3-0.6b AdamW step on one described
     v5e, compiled: (compiled executable, its total memory in bytes)."""
     from repro.configs import get_arch
+
+    return _train_step(topo, get_arch("qwen3-0.6b"), batch_rows, seq)
+
+
+def _train_step(topo, cfg, batch_rows: int, seq: int):
+    """``cfg``'s AdamW step on one described v5e, compiled: (compiled
+    executable, its total memory in bytes)."""
     from repro.distributed.sharding import default_rules, shardings_for
     from repro.launch.mesh import make_host_mesh
     from repro.optim.adamw import AdamWConfig
@@ -126,7 +133,6 @@ def _qwen3_train_step(topo, batch_rows: int, seq: int):
         batch_axes_for, batch_shardings, build_train_step, make_train_state,
     )
 
-    cfg = get_arch("qwen3-0.6b")
     rules = default_rules(make_host_mesh(devices=topo.devices[:1]))
     axes = {}
 
@@ -174,4 +180,36 @@ def test_qwen3_train_step_uses_flash_kernel(topo):
     assert "f32[2,16,512,4096]" not in hlo
     assert layers.ATTN_STATS["kernel_calls"] >= 1
     assert layers.ATTN_STATS["xla_calls"] == 0
+    assert total < 16 * GiB, total / GiB
+
+
+def test_granite_train_step_uses_flash_and_ssd_kernels(topo):
+    """The granite-h-train-8k step (Granite-4.0-H-Micro's first 10 layers at
+    published widths, 1 x 8192, AdamW) on one described v5e: the NoPE
+    attention layer (head width 64) takes the flash kernel and every
+    Mamba-2 layer the SSD chunk kernel, no (B, chunks, Q, Q, H) float32
+    tensor is in the step, and the compiler's total fits 16 GiB (about
+    15.6 GiB: the state is 8.9 GiB)."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.models import layers, mamba2
+
+    full = get_arch("granite-4.0-h-micro")
+    cfg = dataclasses.replace(full, n_layers=10,
+                              layer_types=full.layer_types[:10])
+    assert cfg.runs() == [("mamba", 5), ("attention", 1), ("mamba", 4)]
+    layers.reset_attn_stats()
+    mamba2.reset_ssd_stats()
+    compiled, total = _train_step(topo, cfg, 1, 8192)
+    hlo = compiled.as_text()
+    calls = [ln.split(" = ")[0].strip() for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    # flash: forward, its remat recompute, dq, dk/dv; SSD: the forward and
+    # its recompute in each of the two Mamba-2 runs
+    assert sum("flash_attention" in c for c in calls) == 4, calls
+    assert sum(c.startswith("%ssd") for c in calls) == 4, calls
+    assert (layers.ATTN_STATS["xla_calls"], mamba2.SSD_STATS["xla_calls"]) \
+        == (0, 0)
+    assert "f32[1,32,256,256,64]" not in hlo
     assert total < 16 * GiB, total / GiB

@@ -221,9 +221,10 @@ def test_serving_doc_names_every_sweep_knob():
 
 def test_host_and_device_names_documented():
     """Every `RUN_STATS` key, `run_batch` phase span, model scope and
-    `ATTN_STATS` key is on docs/observability.md, so the program's names
-    cannot drift from it."""
+    `ATTN_STATS` and `SSD_STATS` key is on docs/observability.md, so the
+    program's names cannot drift from it."""
     from repro.models.layers import ATTN_STATS
+    from repro.models.mamba2 import SSD_STATS
     from repro.sim.batch import RUN_STATS
 
     doc = (DOCS / "observability.md").read_text()
@@ -231,9 +232,12 @@ def test_host_and_device_names_documented():
     spans = re.findall(r'_phase\("(repro\.sim\.\w+)"', src)
     assert len(spans) == 4
     models = "".join((ROOT / "src" / "repro" / d).read_text() for d in (
-        "models/lm.py", "models/layers.py", "optim/adamw.py"))
+        "models/lm.py", "models/layers.py", "models/mamba2.py",
+        "optim/adamw.py"))
     scopes = set(re.findall(r'named_scope\("(\w+)"\)', models))
-    assert scopes == {"embed", "attn", "mlp", "norm", "head_loss", "adamw"}
-    missing = [n for n in (*RUN_STATS, *spans, *scopes, *ATTN_STATS)
+    assert scopes == {"embed", "attn", "ssm", "mlp", "norm", "head_loss",
+                      "adamw"}
+    missing = [n for n in (*RUN_STATS, *spans, *scopes, *ATTN_STATS,
+                           *SSD_STATS)
                if f"`{n}`" not in doc]
     assert not missing, missing

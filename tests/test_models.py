@@ -105,6 +105,7 @@ def test_full_configs_match_assignment(arch_id):
         "musicgen-large": (48, 2048, 32, 32, 8192, 2048),
         "mamba2-1.3b": (48, 2048, 0, 0, 0, 50280),
         "zamba2-1.2b": (38, 2048, 32, 32, 8192, 32000),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }[arch_id]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
            cfg.d_ff, cfg.vocab)
@@ -119,6 +120,9 @@ def test_full_configs_match_assignment(arch_id):
         assert cfg.ssm_state == 64 and cfg.attn_every == 6
     if arch_id == "qwen3-0.6b":
         assert cfg.qk_norm
+    if arch_id == "granite-4.0-h-micro":
+        assert (cfg.ssm_state, cfg.hd, cfg.layer_types.count("attention"),
+                cfg.tie_embeddings, cfg.rope) == (128, 64, 4, True, False)
 
 
 def test_param_count_sane():

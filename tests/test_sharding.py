@@ -106,12 +106,12 @@ def test_collective_stats_parsing():
 def test_40_cells_defined():
     from repro.configs import ARCH_IDS, all_cells
     cells = all_cells()
-    assert len(cells) == 40
+    assert len(cells) == 44
     skips = [c for c in cells if not c[2]]
-    assert len(skips) == 8  # 8 quadratic archs skip long_500k
+    assert len(skips) == 9  # 9 archs with full attention skip long_500k
     assert all(s[1] == "long_500k" for s in skips)
     runnable = [c for c in cells if c[2]]
-    assert len(runnable) == 32
+    assert len(runnable) == 35
 
 
 @pytest.mark.parametrize("arch_id", ["phi3-medium-14b", "musicgen-large",
