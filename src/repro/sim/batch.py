@@ -58,9 +58,13 @@ vectorized):
 * ``wf``  (K, W, 6+loops+dias) — status/pc/iv/ready_at/issued/mem_ops plus
   the loop/diamond branch counters: one row gather + one row scatter per
   selected warp instead of one dispatch per field.
-* ``rv``  (K, W, regs+preds, 2) — register/predicate ready-times and the
-  from-mem flag as one value plane; dst+pred writeback is a single scatter
-  (out-of-bounds indices drop masked writes, no read-modify-write).
+* ``rv``/``rm``  (K, W, regs+preds) — register/predicate ready-times and
+  the from-mem flag, as two planes with the register axis minor, so every
+  reader and writer wants one layout and the compiled loop never copies
+  either plane into another (a trailing (time, flag) pair axis made a
+  TPU relayout both ways on every issue slot).  The dst+pred writeback is
+  one scatter into each plane with the same indices (out-of-bounds
+  indices drop masked writes, no read-modify-write).
 * ``cf``  (K, W, 2+S+PS) — cached max/mem-max/per-operand ready times of
   each warp's *current* instruction, refreshed only when that warp's state
   changes (its own issue or prefetch, exactly the scalar cache-invalidation
@@ -577,7 +581,8 @@ def _build(lanes: Sequence[_Lane]):
         "budget": np.zeros(K, bool),
         "wf": wf,
         "cf": np.zeros((K, W, 2 + S + PS), i64),
-        "rv": np.zeros((K, W, RVW, 2), i64),
+        "rv": np.zeros((K, W, RVW), i64),
+        "rm": np.zeros((K, W, RVW), i32),
         "act": np.zeros((K, A), i32),
         "na": np.zeros(K, i32),
         "res": np.zeros((K, W), bool),
@@ -654,10 +659,9 @@ def _run_jax(co, st):
         row at its (post-update) pc."""
         sidx = md[:, M_S: M_S + S]                          # (K, S)
         pidx = md[:, M_PS: M_PS + PS]                       # (K, PS)
-        rvw = s["rv"][kk[:, None], wid[:, None], sidx]      # (K, S, 2)
-        ts = rvw[:, :, 0]
-        fm = rvw[:, :, 1] > 0
-        tp = s["rv"][kk[:, None], wid[:, None], R + 1 + pidx, 0]
+        ts = s["rv"][kk[:, None], wid[:, None], sidx]       # (K, S)
+        fm = s["rm"][kk[:, None], wid[:, None], sidx] > 0
+        tp = s["rv"][kk[:, None], wid[:, None], R + 1 + pidx]
         cmax = jnp.maximum(ts.max(axis=1), tp.max(axis=1))
         cmem = jnp.where(fm, ts, 0).max(axis=1)
         newcf = jnp.concatenate([cmax[:, None], cmem[:, None], ts, tp],
@@ -679,12 +683,13 @@ def _run_jax(co, st):
         return s, done
 
     def prefetch_charge(s, wid, ii, body, done):
-        """Max the fetched interval's registers up to the landing time."""
+        """Max the fetched interval's registers up to the landing time (the
+        from-mem flags stay as they are)."""
         regs = co["ivregs"][kk, ii]                     # (K, GV)
         vp = (regs >= 0) & body[:, None]
         ridx = jnp.where(vp, regs, RVW)                 # OOB: masked drop
         val = jnp.where(vp, fb.from_int(done)[:, None], 0)
-        s["rv"] = s["rv"].at[kk[:, None], wid[:, None], ridx, 0].max(val)
+        s["rv"] = s["rv"].at[kk[:, None], wid[:, None], ridx].max(val)
         return s
 
     def activation(s, act):
@@ -846,8 +851,8 @@ def _run_jax(co, st):
                               jnp.where(is_ld,
                                         fb.add(fb.from_int(mlat), co["wlat"]),
                                         co["aluw"])))
-        # dst-register + dst-predicate writeback: ONE scatter into the
-        # unified (reg | pred) value plane, masked rows dropped via OOB
+        # dst-register + dst-predicate writeback: one scatter into each
+        # (reg | pred) plane at the same indices, masked rows dropped via OOB
         pd = md[:, M_PDST]
         onp = ok & is_set & (pd < PRS)
         dsts = md[:, M_D: M_D + DD]                     # (K, DD)
@@ -858,10 +863,10 @@ def _run_jax(co, st):
         vt = jnp.concatenate(
             [jnp.broadcast_to(da[:, None], ond.shape), da[:, None]], axis=1)
         vm = jnp.concatenate(
-            [(ond & is_ld[:, None]).astype(i64),
-             jnp.zeros((K, 1), i64)], axis=1)
-        s["rv"] = s["rv"].at[kk[:, None], wsel[:, None], wix].set(
-            jnp.stack([vt, vm], axis=2))
+            [ond & is_ld[:, None], jnp.zeros((K, 1), bool)], axis=1)
+        s["rv"] = s["rv"].at[kk[:, None], wsel[:, None], wix].set(vt)
+        s["rm"] = s["rm"].at[kk[:, None], wsel[:, None], wix].set(
+            vm.astype(s["rm"].dtype))
         happened = bra | ext | ok
         # branch resolution (loop trip counters / diamond visit hashes);
         # the counters live in the warp-family row — updated in place via

@@ -13,6 +13,7 @@ every worker imports this file.  Keep all such compiles in this one file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,26 +94,75 @@ def test_ssd_scan_compiles(one_chip):
                 _sds((B, S, N), jnp.bfloat16, one_chip))
 
 
-def test_batch_engine_chunk_compiles(one_chip):
-    """One 4-lane chunk of the jitted lockstep simulator, with int64 state
-    and no floating point (a TPU's f64 is emulated and not IEEE-exact)."""
+def _batch_chunk(one_chip, kernels, designs, num_warps: int):
+    """The batch engine's lockstep loop for one chunk, a lane for every
+    (kernel, design) at Table-2 #7, compiled for one described v5e: (its
+    initial state arrays, the lowered text, the compiled HLO text)."""
     from repro.sim import design_config
     from repro.sim.batch import _Lane, _build, _encode_plan, _occupancy, _run_jax
     from repro.workloads import get_workload
 
     lanes = []
-    for name in ("srad", "kmeans"):
-        for design in ("BL", "RFC"):    # the token-bucket designs
+    for name in kernels:
+        for design in designs:
             w = get_workload(name)
-            cfg = design_config(design, table2_config=7, num_warps=16)
+            cfg = design_config(design, table2_config=7, num_warps=num_warps)
             lanes.append(_Lane(w, cfg, _encode_plan(w, cfg), _occupancy(w, cfg)))
     co, st = _build(lanes)
     with jax.enable_x64(True):
         args = [{k: _sds(v.shape, v.dtype, one_chip) for k, v in d.items()}
                 for d in (co, st)]
         lowered = jax.jit(_run_jax).lower(*args)
-        assert lowered.compile().as_text()
-    assert "f64" not in lowered.as_text()
+        return st, lowered.as_text(), lowered.compile().as_text()
+
+
+def test_batch_engine_chunk_compiles(one_chip):
+    """One 4-lane chunk of the jitted lockstep simulator, with int64 state
+    and no floating point (a TPU's f64 is emulated and not IEEE-exact)."""
+    _, lowered, compiled = _batch_chunk(one_chip, ("srad", "kmeans"),
+                                        ("BL", "RFC"),  # token-bucket designs
+                                        num_warps=16)
+    assert compiled
+    assert "f64" not in lowered
+
+
+def _while_bodies(hlo: str) -> dict[str, list[str]]:
+    """The instruction lines of every while-loop body in compiled HLO
+    text, by computation name."""
+    comps, name = {}, None
+    for ln in hlo.splitlines():
+        if ln and not ln[0].isspace() and ln.endswith("{"):
+            name = ln.split()[1] if ln.startswith("ENTRY") else ln.split()[0]
+            comps[name.lstrip("%")] = []
+        elif ln.startswith("}"):
+            name = None
+        elif name:
+            comps[name.lstrip("%")].append(ln)
+    bodies = re.findall(r"\swhile\(.*?body=%?([\w.\-]+)", hlo)
+    return {b: comps[b] for b in bodies}
+
+
+@pytest.mark.parametrize("design", ["BL", "LTRF"])
+def test_batch_engine_loop_keeps_value_planes_in_place(one_chip, design):
+    """The sim-fig14 bucket (8 lanes over its 8 kernels, Table-2 #7, 64
+    warps) compiled for a v5e: neither (K, W, regs+preds) value plane is
+    copied inside the tick loop or either activation loop.  A copy there is
+    a relayout of the whole plane on every tick; a trailing (time, flag)
+    pair axis made 14 in the tick body and 2 in each activation body,
+    each padded 64x to the TPU's 128-lane tiles."""
+    st, _, hlo = _batch_chunk(
+        one_chip, ("pathfinder", "kmeans", "bfs", "btree", "nw", "dct8x8",
+                   "mri-q", "gaussian"), (design,), num_warps=64)
+    # (K, W, RVW) of any element type (an s64 plane lowers to u32 halves on
+    # the TPU), with or without trailing axes
+    plane = ",".join(map(str, st["rv"].shape[:3]))
+    copy = re.compile(r"= \(?\w+\[%s[\],][^=]*\scopy(-start)?\(" % plane)
+    bodies = _while_bodies(hlo)
+    assert len(bodies) == 3, list(bodies)     # tick + two activation loops
+    found = {b: [ln.strip()[:120] for ln in lines if copy.search(ln)]
+             for b, lines in bodies.items()}
+    assert not any(found.values()), found
+    assert st["rm"].shape == st["rv"].shape
 
 
 def _qwen3_train_step(topo, batch_rows: int, seq: int):

@@ -96,6 +96,34 @@ def test_fma_contraction_regression_pin():
     assert simulate_one(w, cfg) == simulate(w, cfg)
 
 
+@pytest.mark.parametrize("name", ["kmeans", "btree"])
+def test_prefetch_keeps_load_flag(name):
+    """LTRF, Table-2 #7, 4 warps: interval prefetches land on registers
+    whose last write was a load.  A prefetch maxes the register's ready
+    time and leaves its from-memory flag set (golden's `_start_prefetch`),
+    so a source still waiting on that load counts as a memory stall and
+    may swap the warp out.  The batch engine keeps the flag in a plane of
+    its own that the prefetch scatter never writes; every counter and the
+    breakdown equal golden's.  The control clears the flags a prefetch
+    fetches, and golden's counters then move: the case exercises the
+    rule."""
+    from repro.sim.golden import GoldenSimulator, golden_simulate
+
+    class FlagClearingGolden(GoldenSimulator):
+        def _start_prefetch(self, wp, cycle, force=False):
+            before = self.result.prefetch_ops
+            super()._start_prefetch(wp, cycle, force)
+            if self.result.prefetch_ops > before:
+                for r in self.pf_ops[wp.interval].bitvector:
+                    wp.reg_from_mem[r] = False
+
+    w = WORKLOADS[name]
+    cfg = design_config("LTRF", table2_config=7, num_warps=4)
+    ref = golden_simulate(w, cfg)
+    assert simulate_one(w, cfg) == ref
+    assert FlagClearingGolden(cfg, w).run() != ref
+
+
 def test_budget_outcomes_returned_not_raised():
     """`run_batch` reports watchdog trips as `SimBudgetExceeded` instances
     in the outcome list (the sweep service records them as job outcomes);
