@@ -5,9 +5,9 @@ One function per artifact; all results cached to experiments/paper/ as JSON
 benchmarks.run` prints every table as CSV.
 
 Every figure enumerates its simulation grid up front and hands it to the
-sweep orchestrator (`benchmarks.orchestrator`): jobs are deduplicated
-against the in-process/on-disk caches and the misses run across a process
-pool, so the full artifact set costs one pass over the unique design points.
+sweep service (`repro.serving.sweep`): jobs are deduplicated against the
+in-process/on-disk caches and the misses run across a process pool, so the
+full artifact set costs one pass over the unique design points.
 """
 from __future__ import annotations
 
@@ -15,11 +15,11 @@ import json
 import math
 import pathlib
 
-from benchmarks.orchestrator import default_runner
 from repro.core.plan_cache import (
     cached_intervals, cached_prefetch_ops, cached_renumber,
 )
 from repro.core.prefetch import code_size_overhead, conflict_distribution
+from repro.serving.sweep import default_runner
 from repro.sim import (
     SimConfig, baseline_config, design_config, max_tolerable_latency,
 )

@@ -1,19 +1,26 @@
-"""Benchmark harness entry point: one artifact per paper table/figure,
-plus kernel microbenches and the dry-run/roofline summaries.
+"""Paper reproductions and the analytic tier's calibration fit.
 
-Prints ``name,metric,value`` CSV rows (plus per-workload detail rows).
-Heavy artifacts are cached under experiments/paper/.  ``--strict`` turns a
+    python -m benchmarks.run [paper] [--strict] [--suite synth|traced|all]
+    python -m benchmarks.run calibrate [--suite synth|traced|all]
+
+``paper`` (the default) prints one artifact per paper table/figure as
+``name,metric,value`` CSV rows (plus per-workload detail rows); heavy
+artifacts are cached under experiments/paper/.  ``--strict`` turns a
 degraded sweep (failed or quarantined design points — see
 `repro.serving.sweep`) from a stderr warning into a non-zero exit.
+
+``calibrate`` re-fits the analytical tier's exposure coefficients against
+engine runs of the tracked sweep domain (`benchmarks.sweep_subset`) and
+saves them in the sim cache, where `SimRunner` picks them up; it prints
+the fitted calibration.
+
+Neither mode measures speed: the benchmark is ``python3 bench/run.py``.
 """
 from __future__ import annotations
 
 import json
-import pathlib
 import sys
 import time
-
-ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _emit(name: str, rows) -> None:
@@ -60,106 +67,34 @@ def bench_paper_figures(strict: bool = False) -> None:
                      f"(run_id {health['run_id']})")
 
 
-def bench_sim_sweep(suite: str | None = None, strict: bool = False) -> None:
-    """Time the tracked paper-figure sweep subset and refresh BENCH_sim.json
-    (see benchmarks.bench_sim; pass REPRO_SIM_PROCS to bound the pool)."""
-    from benchmarks.bench_sim import run_bench
-    report = run_bench(smoke="--smoke" in sys.argv, suite=suite)
-    _emit("sim", {k: v for k, v in report.items() if not isinstance(v, dict)})
-    sweep_report = report["sim_cache"]["sweep_report"]
-    if not sweep_report["ok"]:
-        print(f"# WARNING: sim sweep degraded "
-              f"[run_id {sweep_report['run_id']}]: "
-              f"{len(sweep_report['failed'])} failed, "
-              f"{len(sweep_report['quarantined'])} quarantined",
-              file=sys.stderr)
-        if strict:
-            sys.exit(f"# --strict: refusing to pass a degraded sim sweep "
-                     f"(run_id {sweep_report['run_id']})")
+def calibrate(jobs=None, runner=None, suite: str | None = None):
+    """Fit the analytic tier's calibration on engine runs of ``jobs``
+    (default: the tracked sweep domain of ``suite``), save it where
+    ``runner`` (default: a `SimRunner` on the shared sim cache) reads it,
+    print it, and return it."""
+    from benchmarks.sweep_subset import sweep_jobs
+    from repro.serving.sweep import CALIBRATION_KEY, SimRunner
+    from repro.sim.analytic import (analytic_supported, calibration_to_dict,
+                                    fit_calibration, save_calibration)
+    from repro.workloads import get_workload
 
-
-def bench_kernels() -> None:
-    """Interpret-mode micro-bench: wall time is NOT TPU perf — this verifies
-    the kernels execute and reports call latencies for regression tracking."""
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels.flash_attention.ops import flash_attention
-    from repro.kernels.ltrf_matmul.ops import ltrf_matmul
-    from repro.kernels.ssd_scan.ops import ssd_scan
-
-    def timed(fn, *args, n=3, **kw):
-        fn(*args, **kw)  # warmup/compile
-        t0 = time.time()
-        for _ in range(n):
-            jax.block_until_ready(fn(*args, **kw))
-        return (time.time() - t0) / n * 1e6
-
-    x = jax.random.normal(jax.random.PRNGKey(0), (256, 512), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(1), (512, 256), jnp.float32)
-    us = timed(ltrf_matmul, x, w, bm=128, bk=128, bn=128, interpret=True)
-    _emit("kernels", {"name": "ltrf_matmul_256x512x256", "us_per_call": us})
-
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 4, 256, 64))
-    k = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 256, 64))
-    v = jax.random.normal(jax.random.PRNGKey(2), (1, 2, 256, 64))
-    us = timed(flash_attention, q, k, v, bq=128, bk=128, interpret=True)
-    _emit("kernels", {"name": "flash_attention_b1h4s256", "us_per_call": us})
-
-    xs = jax.random.normal(jax.random.PRNGKey(0), (1, 128, 2, 8)) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(jax.random.PRNGKey(1), (1, 128, 2)))
-    A = -jnp.exp(jnp.linspace(0.0, 1.0, 2))
-    Bm = jax.random.normal(jax.random.PRNGKey(2), (1, 128, 8)) * 0.3
-    Cm = jax.random.normal(jax.random.PRNGKey(3), (1, 128, 8)) * 0.3
-    us = timed(ssd_scan, xs, dt, A, Bm, Cm, chunk=32, interpret=True)
-    _emit("kernels", {"name": "ssd_scan_s128", "us_per_call": us})
-
-
-def bench_dryrun_summary() -> None:
-    d = ROOT / "experiments" / "dryrun"
-    if not d.exists():
-        print("# dry-run JSONs missing; run python -m repro.launch.dryrun --all",
-              file=sys.stderr)
-        return
-    for p in sorted(d.glob("*.json")):
-        r = json.loads(p.read_text())
-        if not r.get("runnable", True):
-            _emit("dryrun", {"arch": r["arch"], "shape": r["shape"],
-                             "mesh": r["mesh"], "status": "defined-skip"})
-            continue
-        mem = r.get("memory", {}).get("total_hbm_bytes", 0) / 2 ** 30
-        _emit("dryrun", {
-            "arch": r["arch"], "shape": r["shape"], "mesh": r["mesh"],
-            "status": "ok" if r.get("ok") else "FAIL",
-            "mem_gib": mem,
-            "coll_mib": r.get("collectives", {}).get("total_bytes", 0) / 2 ** 20,
-            "compile_s": r.get("compile_s", -1),
-        })
-
-
-def bench_roofline_summary() -> None:
-    d = ROOT / "experiments" / "roofline"
-    if not d.exists():
-        print("# roofline JSONs missing; run python -m benchmarks.roofline --all",
-              file=sys.stderr)
-        return
-    for p in sorted(d.glob("*.json")):
-        r = json.loads(p.read_text())
-        if "skipped" in r or "error" in r:
-            continue
-        t = r["terms_seconds"]
-        _emit("roofline", {
-            "arch": r["arch"], "shape": r["shape"],
-            "compute_ms": t["compute_s"] * 1e3,
-            "memory_ms": t["memory_s"] * 1e3,
-            "collective_ms": t["collective_s"] * 1e3,
-            "dominant": r["dominant"].replace("_s", ""),
-            "useful_flop_ratio": r["useful_flop_ratio"],
-            "roofline_fraction": r["roofline_fraction"],
-        })
+    runner = runner or SimRunner()
+    if jobs is None:
+        jobs = sweep_jobs(suite=suite)
+    jobs = [j for j in dict.fromkeys(jobs) if analytic_supported(j[1])]
+    runner.prefill(jobs, tier="engine")
+    samples = [(get_workload(n), cfg, runner.sim(n, cfg).cycles)
+               for n, cfg in jobs]
+    calib = fit_calibration(samples)
+    path = runner.store.path(CALIBRATION_KEY)
+    save_calibration(calib, path)
+    print(f"# wrote {path}", file=sys.stderr)
+    print(json.dumps(calibration_to_dict(calib), indent=1))
+    return calib
 
 
 def main() -> None:
-    args = [a for a in sys.argv[1:] if a != "--smoke"]
+    args = sys.argv[1:]
     strict = "--strict" in args
     if strict:
         # fail the process (CI job) when any sweep is degraded — failed or
@@ -174,23 +109,18 @@ def main() -> None:
         if suite not in ("synth", "traced", "all"):
             sys.exit(f"unknown suite {suite!r} (expected synth|traced|all)")
         del args[i:i + 2]
-    only = args[0] if args else None
-    if suite:
-        # run the figure set over another workload suite (e.g. the lifted
-        # real kernels: --suite traced); artifacts gain a suffix
-        from benchmarks import paper_figs
-        paper_figs.set_suite(suite)
-    benches = {
-        "paper": lambda: bench_paper_figures(strict=strict),
-        "sim": lambda: bench_sim_sweep(suite=suite, strict=strict),
-        "kernels": bench_kernels,
-        "dryrun": bench_dryrun_summary,
-        "roofline": bench_roofline_summary,
-    }
-    for name, fn in benches.items():
-        if only and name != only:
-            continue
-        fn()
+    mode = args[0] if args else "paper"
+    if mode == "paper":
+        if suite:
+            # run the figure set over another workload suite (e.g. the
+            # lifted real kernels: --suite traced); artifacts gain a suffix
+            from benchmarks import paper_figs
+            paper_figs.set_suite(suite)
+        bench_paper_figures(strict=strict)
+    elif mode == "calibrate":
+        calibrate(suite=suite)
+    else:
+        sys.exit(f"unknown mode {mode!r} (expected paper|calibrate)")
 
 
 if __name__ == "__main__":

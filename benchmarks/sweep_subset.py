@@ -1,9 +1,11 @@
-"""The canonical paper-figure sweep subset used for perf tracking.
+"""The simulator's canonical sweep grids.
 
-This is the Fig. 14-shaped grid (both Table-2 design points x all designs x
-the workload suite, plus the per-workload normalization baselines) that
-`BENCH_sim.json` times.  Kept in its own module so the pre/post-change
-measurements are guaranteed to run the *same* job list.
+`sweep_jobs` is the Fig. 14-shaped grid (both Table-2 design points x all
+designs x the workload suite, plus the per-workload normalization
+baselines): the benchmark's `sim-fig14` cell checks its job list against
+it, and ``python -m benchmarks.run calibrate`` fits the analytic tier on
+it.  `screening_jobs` is the analytic tier's screening grid and
+`interval_sweep_jobs` the interval-formation ablation.
 
 The default job list is pinned to the synthetic suite (`workload_names()`
 with no suite): lazily-registered suites like ``traced`` never change the
@@ -22,13 +24,9 @@ SWEEP_DESIGNS = ("BL", "RFC", "SHRF", "LTRF", "LTRF_conf", "LTRF_plus", "Ideal")
 # capacity-clamped variant vs naive fixed-length intervals, swept at an
 # interval_cap deliberately larger than the default design's RFC
 # entries-per-warp (128 entries / 8 active slots = 16) so the capacity
-# strategy actually clamps.  Verdicts are computed on LTRF_conf — the
-# paper's full compile pipeline (intervals + ICG renumbering).
+# strategy actually clamps.
 INTERVAL_STRATEGIES_SWEPT = ("paper", "capacity", "fixed:8")
 INTERVAL_SWEEP_CAP = 48
-INTERVAL_VERDICT_DESIGN = "LTRF_conf"
-
-GPU_SCHEDULERS = ("two_level", "gto", "lrr")
 
 # The cycle-attribution comparison points (ISSUE 7): the baseline that eats
 # the slow-MRF latency raw, vs the two paper designs that hide it behind
@@ -38,54 +36,13 @@ GPU_SCHEDULERS = ("two_level", "gto", "lrr")
 # entries with Fig. 14.
 BREAKDOWN_DESIGNS = ("BL", "LTRF", "LTRF_conf")
 
-# The §4.3 renumbering-ablation comparison points: LTRF with the full ICG
-# renumbering pipeline, the same design with the coloring pass ablated
-# (identity numbering), and the BL reference — all under the arbitrated
-# bank model so operand/writeback conflicts are actually charged.
-BANK_VARIANTS = (
-    ("BL", "icg"),
-    ("LTRF_conf", "icg"),
-    ("LTRF_conf", "identity"),
-)
-
-
-def bank_sweep_jobs(workloads=None, table2_config: int = 7,
-                    variants=BANK_VARIANTS,
-                    suite: str | None = None) -> list[tuple[str, SimConfig]]:
-    """The bank-arbitration/renumbering ablation recorded in BENCH_sim.json
-    (and run as the CI bank smoke).  Single-SM configs: run them through
-    `SimRunner.sim` like the main sweep."""
-    names = list(workloads) if workloads else list(workload_names(suite))
-    return [
-        (name, design_config(d, table2_config=table2_config,
-                             bank_model="arbitrated", renumber=rn))
-        for name in names for d, rn in variants
-    ]
-
-
-def gpu_sweep_jobs(num_sms: int = 2, warps_per_sm: int = 16,
-                   workloads=("srad", "bfs"), designs=("BL", "LTRF"),
-                   schedulers=GPU_SCHEDULERS,
-                   table2_config: int = 7) -> list[tuple[str, SimConfig]]:
-    """The multi-SM scheduler-sensitivity mini-sweep recorded in
-    BENCH_sim.json (and run as the CI GPU-scale smoke).  Each job's config
-    is a *whole-GPU* config: run it through `SimRunner.sim_gpu` /
-    `repro.sim.gpu.simulate_gpu`, not the single-SM engine."""
-    return [
-        (name, design_config(d, table2_config=table2_config,
-                             num_warps=warps_per_sm * num_sms,
-                             num_sms=num_sms, scheduler=s))
-        for name in workloads for d in designs for s in schedulers
-    ]
-
 
 def interval_sweep_jobs(workloads=None, table2_config: int = 7,
                         strategies=INTERVAL_STRATEGIES_SWEPT,
                         interval_cap: int = INTERVAL_SWEEP_CAP,
                         designs=SWEEP_DESIGNS,
                         suite: str | None = None) -> list[tuple[str, SimConfig]]:
-    """The interval-strategy ablation recorded in BENCH_sim.json (and run as
-    the CI interval smoke).  Defaults to the *high-register-pressure*
+    """The interval-strategy ablation.  Defaults to the *high-register-pressure*
     (register-sensitive) workloads of the suite — the kernels whose working
     sets the strategies actually shape.  Single-SM configs: run them
     through `SimRunner.sim` like the main sweep."""
@@ -96,19 +53,6 @@ def interval_sweep_jobs(workloads=None, table2_config: int = 7,
         (name, design_config(d, table2_config=table2_config,
                              interval_cap=interval_cap, interval_strategy=s))
         for name in workloads for d in designs for s in strategies
-    ]
-
-
-def breakdown_sweep_jobs(workloads=None, table2_config: int = 7,
-                         designs=BREAKDOWN_DESIGNS,
-                         suite: str | None = None) -> list[tuple[str, SimConfig]]:
-    """The cycle-attribution sweep recorded in BENCH_sim.json's
-    ``cycle_breakdown`` section (and run as the CI obs smoke).  Single-SM
-    configs: run them through `SimRunner.sim` like the main sweep."""
-    names = list(workloads) if workloads else list(workload_names(suite))
-    return [
-        (name, design_config(d, table2_config=table2_config))
-        for name in names for d in designs
     ]
 
 
@@ -149,13 +93,3 @@ def screening_jobs(workloads=None, designs=SWEEP_DESIGNS,
         for m in mults for s in schedulers
     ))
 
-
-def run_tier_sweep(jobs, tier: str, runner=None, top_k: int = 3):
-    """Run `jobs` at `tier` through a `SimRunner`, returning
-    ``(runner, report)``.  Thin convenience for notebooks/benchmarks: the
-    caller keeps the runner to read confirmed `sim()` results or fast
-    `estimate()`s afterwards."""
-    from repro.serving.sweep import SimRunner
-    runner = runner or SimRunner(processes=1)
-    report = runner.prefill(list(jobs), tier=tier, top_k=top_k)
-    return runner, report

@@ -11,7 +11,8 @@ prints one JSON line, and the last line of stdout is the verdict::
 Phases (one chip):
 
 * ``sim``: the jitted lockstep batch engine (`repro.sim.batch.run_batch`,
-  no host fallback) on the batch-smoke matrix, two traced-kernel jobs and a
+  no host fallback) on 20 jobs (srad and kmeans x BL, RFC, LTRF,
+  LTRF_plus and Ideal x 8 and 16 warps), two traced-kernel jobs and a
   200-cycle watchdog job.  Every outcome must equal the host event-heap
   engine's, every counter and the whole cycle breakdown, with no tolerance.
 * ``train``: three AdamW steps of qwen3-0.6b at full width and depth
@@ -83,13 +84,12 @@ class CompileLog:
 def phase_sim() -> dict:
     from dataclasses import replace
 
-    from benchmarks.bench_sim import SMOKE_WORKLOADS
     from repro.sim import SimBudgetExceeded, design_config, simulate
     from repro.sim.batch import reset_run_stats, run_batch
     from repro.workloads import get_workload
 
     jobs = [(get_workload(n), design_config(d, table2_config=7, num_warps=nw))
-            for n in SMOKE_WORKLOADS
+            for n in ("srad", "kmeans")
             for d in ("BL", "RFC", "LTRF", "LTRF_plus", "Ideal")
             for nw in (8, 16)]
     jobs += [(get_workload("traced_matmul"),
@@ -289,7 +289,7 @@ def main(argv=None) -> int:
         print(json.dumps({"ok": False, "error": f"no TPU: JAX found "
                           f"{dev.platform!r}"}))
         return 2
-    sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
+    sys.path[:0] = [str(ROOT / "src")]
     try:
         from repro.launch.compile_cache import enable_compile_cache
     except ImportError as e:

@@ -56,7 +56,6 @@ class ArchConfig:
     dtype: str = "bfloat16"
     kv_dtype: str = ""          # decode KV-cache dtype ("" -> dtype; e.g. float8_e4m3fn)
     remat: str = "full"         # none | block | full (full = recompute blocks)
-    scan_layers: bool = True    # False: unrolled python loop (roofline probes)
     q_block: int = 512          # attention q-block (memory-efficient scan)
     source: str = ""            # provenance note
 

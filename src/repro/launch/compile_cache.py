@@ -5,7 +5,7 @@ module sets nothing.  Otherwise the cache lives at ``<repo>/.jax_cache``: a
 fixed path, because the path is part of the cache key, and inside the
 checkout, because the program writes nothing outside it.  Libraries never
 call this; entry points (`chip_smoke.py`, `repro.launch.train`,
-`repro.launch.serve`, `benchmarks.bench_sim`) call it once at start-up.
+`repro.launch.serve`) call it once at start-up.
 """
 from __future__ import annotations
 

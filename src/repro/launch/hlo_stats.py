@@ -1,4 +1,4 @@
-"""Side-effect-free helpers shared by dryrun/roofline/hillclimb/tests.
+"""Side-effect-free helpers shared by dryrun and the tests.
 
 (dryrun.py sets XLA_FLAGS at import, so anything that does NOT want 512 fake
 devices must import from here instead.)

@@ -12,8 +12,7 @@ then ``x += r * mlp(norm(x))``, the mixer Mamba-2 (``"mamba"``) or causal
 GQA (``"attention"``).  Each maximal run of one kind is one parameter tree
 stacked over its layers (``params["layers"]``, a list in pattern order) and
 one ``lax.scan``; the decode cache holds conv and SSM state for a Mamba-2
-run and K/V for an attention run, in the same list.  Such a configuration
-is always scanned (``scan_layers`` does not apply).
+run and K/V for an attention run, in the same list.
 
 The training step's operations carry fixed ``jax.named_scope`` names,
 shared by every family: ``embed``, ``attn``, ``ssm`` (the Mamba-2 mixer),
@@ -251,10 +250,6 @@ def _maybe_remat(fn, cfg: ArchConfig):
 # full forward
 # ---------------------------------------------------------------------------
 
-def _layer_slice(layers, i):
-    return jax.tree.map(lambda a: a[i], layers)
-
-
 def forward(params, cfg: ArchConfig, x, positions):
     """Backbone over embedded inputs x: (B, S, D) -> (B, S, D)."""
     if cfg.layer_types:
@@ -264,8 +259,6 @@ def forward(params, cfg: ArchConfig, x, positions):
             x, _ = jax.lax.scan(lambda xx, p: (blk(xx, p), None), x, run)
         return (_norm(x, params["final_norm"], cfg.norm_eps),
                 jnp.zeros((), jnp.float32))
-    if not cfg.scan_layers:
-        return _forward_unrolled(params, cfg, x, positions)
     if cfg.family in ("dense", "moe", "vlm", "audio"):
         blk = _maybe_remat(
             lambda xx, p: (_dense_block(cfg, p, xx, positions)), cfg)
@@ -303,32 +296,6 @@ def forward(params, cfg: ArchConfig, x, positions):
         x, _ = jax.lax.scan(group_body, x, head)
         if cfg.n_layers - head_n:
             x, _ = jax.lax.scan(lambda c, p: (blk(c, p), None), x, tail)
-    else:
-        raise ValueError(cfg.family)
-    return _norm(x, params["final_norm"], cfg.norm_eps), aux
-
-
-def _forward_unrolled(params, cfg: ArchConfig, x, positions):
-    """Python-loop variant (scan_layers=False): identical math, unrolled HLO.
-
-    Used by the roofline probes — XLA cost analysis counts a while-loop body
-    once, so per-layer FLOP/byte/collective numbers come from unrolled
-    small-L lowers and are scaled analytically."""
-    aux = jnp.zeros((), jnp.float32)
-    L = cfg.n_layers
-    if cfg.family in ("dense", "moe", "vlm", "audio"):
-        for i in range(L):
-            x, a = _dense_block(cfg, _layer_slice(params["layers"], i), x,
-                                positions)
-            aux = aux + a
-    elif cfg.family == "ssm":
-        for i in range(L):
-            x = _ssm_block(cfg, _layer_slice(params["layers"], i), x)
-    elif cfg.family == "hybrid":
-        for i in range(L):
-            x = _ssm_block(cfg, _layer_slice(params["layers"], i), x)
-            if (i + 1) % cfg.attn_every == 0:
-                x = _shared_block(cfg, params["shared_attn"], x, positions)
     else:
         raise ValueError(cfg.family)
     return _norm(x, params["final_norm"], cfg.norm_eps), aux
@@ -529,18 +496,9 @@ def decode_step(params, cache, tokens, cache_len, cfg: ArchConfig):
             xx = xx + h
             return xx, (ck, cv)
 
-        if cfg.scan_layers:
-            x, (k_new, v_new) = jax.lax.scan(
-                body, x, (params["layers"], cache["k"], cache["v"]))
-            new_cache = {"k": k_new, "v": v_new}
-        else:
-            ks, vs = [], []
-            for i in range(cfg.n_layers):
-                x, (ck, cv) = body(x, (_layer_slice(params["layers"], i),
-                                       cache["k"][i], cache["v"][i]))
-                ks.append(ck)
-                vs.append(cv)
-            new_cache = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+        x, (k_new, v_new) = jax.lax.scan(
+            body, x, (params["layers"], cache["k"], cache["v"]))
+        new_cache = {"k": k_new, "v": v_new}
     elif cfg.family == "ssm":
         def body(xx, layer):
             p, conv, ssm = layer
@@ -550,53 +508,9 @@ def decode_step(params, cache, tokens, cache_len, cfg: ArchConfig):
                                    expand=cfg.ssm_expand, norm_eps=cfg.norm_eps)
             return xx + h, (new["conv"], new["ssm"])
 
-        if cfg.scan_layers:
-            x, (conv_new, ssm_new) = jax.lax.scan(
-                body, x, (params["layers"], cache["conv"], cache["ssm"]))
-            new_cache = {"conv": conv_new, "ssm": ssm_new}
-        else:
-            cs, ss = [], []
-            for i in range(cfg.n_layers):
-                x, (c1, s1) = body(x, (_layer_slice(params["layers"], i),
-                                       cache["conv"][i], cache["ssm"][i]))
-                cs.append(c1)
-                ss.append(s1)
-            new_cache = {"conv": jnp.stack(cs), "ssm": jnp.stack(ss)}
-    elif cfg.family == "hybrid" and not cfg.scan_layers:
-        def one(xx, p, conv, ssm):
-            h = rms_norm(xx, p["norm"], cfg.norm_eps)
-            h, new = mamba2_decode(p["mixer"], h, {"conv": conv, "ssm": ssm},
-                                   d_state=cfg.ssm_state, headdim=cfg.ssm_headdim,
-                                   expand=cfg.ssm_expand, norm_eps=cfg.norm_eps)
-            return xx + h, new
-
-        cs, ss, ks, vs = [], [], [], []
-        g = 0
-        for i in range(cfg.n_layers):
-            x, new = one(x, _layer_slice(params["layers"], i),
-                         cache["conv"][i], cache["ssm"][i])
-            cs.append(new["conv"])
-            ss.append(new["ssm"])
-            if (i + 1) % cfg.attn_every == 0 and g < cache["k"].shape[0]:
-                sp = params["shared_attn"]
-                h = rms_norm(x, sp["norm1"], cfg.norm_eps)
-                h, ck, cv = attention_decode(
-                    sp["attn"], h, cache["k"][g], cache["v"][g], cache_len,
-                    n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
-                    rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps)
-                x = x + h
-                h = rms_norm(x, sp["norm2"], cfg.norm_eps)
-                x = x + mlp_block(sp["mlp"], h)
-                ks.append(ck)
-                vs.append(cv)
-                g += 1
-        while g < cache["k"].shape[0]:
-            ks.append(cache["k"][g])
-            vs.append(cache["v"][g])
-            g += 1
-        new_cache = {"conv": jnp.stack(cs), "ssm": jnp.stack(ss),
-                     "k": jnp.stack(ks) if ks else cache["k"],
-                     "v": jnp.stack(vs) if vs else cache["v"]}
+        x, (conv_new, ssm_new) = jax.lax.scan(
+            body, x, (params["layers"], cache["conv"], cache["ssm"]))
+        new_cache = {"conv": conv_new, "ssm": ssm_new}
     elif cfg.family == "hybrid":
         period = cfg.attn_every
         groups = cfg.n_layers // period

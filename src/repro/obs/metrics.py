@@ -4,8 +4,8 @@ Backs the sweep service's operational telemetry (`repro.serving.sweep`):
 job latency, queue wait, cache hits/misses, retries, pool recycles,
 quarantines.  Two export formats:
 
-* `MetricsRegistry.snapshot()` — a plain-JSON dict (folded into
-  ``BENCH_sim.json`` meta and the ``run.py --strict`` report);
+* `MetricsRegistry.snapshot()` — a plain-JSON dict (folded into the
+  ``benchmarks.run paper --strict`` report);
 * `MetricsRegistry.to_prometheus()` — Prometheus text exposition
   (counters/gauges as samples, histograms as summaries with
   ``quantile=\"0.5|0.95|0.99\"`` plus ``_sum``/``_count``), so a scrape

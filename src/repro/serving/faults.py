@@ -4,9 +4,8 @@ The sweep dispatcher (`repro.serving.sweep`) is fault-*tolerant* code; this
 module makes its failure paths *testable* without flaky timing tricks: a
 fault plan designates specific jobs (by label substring) and makes them
 raise, kill their worker process, hang, or corrupt their cache entry — all
-deterministically, so the chaos suite (`tests/test_sweep_faults.py`) and
-the CI ``bench_sim --chaos-smoke`` step can assert exact `SweepReport`
-contents.
+deterministically, so the chaos suite (`tests/test_sweep_faults.py`) can
+assert exact `SweepReport` contents.
 
 Plans cross the process-pool boundary through the environment: set
 ``REPRO_FAULT_PLAN`` to the path of a JSON plan file before the pool is

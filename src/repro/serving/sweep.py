@@ -2,9 +2,8 @@
 
 The paper's subject is latency *tolerance* — overlapping long-latency
 operations instead of stalling on them — and this module applies the same
-discipline to the sweep infrastructure itself.  The original
-``benchmarks.orchestrator`` died on its first fault: one crashed pool
-worker aborted a whole ``prefill`` with `BrokenProcessPool`, a hung
+discipline to the sweep infrastructure itself.  The original sweep
+orchestrator died on its first fault: one crashed pool worker aborted a whole ``prefill`` with `BrokenProcessPool`, a hung
 simulation blocked a sweep forever, and a corrupt cache entry was silently
 recomputed with no record.  This layer survives all of them:
 
@@ -31,8 +30,8 @@ recomputed with no record.  This layer survives all of them:
   record, and recomputed — never silently trusted or silently dropped;
 * **graceful degradation** — `SimRunner.prefill` returns a `SweepReport`
   (completed / retried / failed / quarantined, per job) instead of raising,
-  so `benchmarks.bench_sim` and `benchmarks.paper_figs` can finish a sweep
-  with annotated missing points rather than crashing.
+  so `benchmarks.paper_figs` can finish a sweep with annotated missing
+  points rather than crashing.
 
 The deterministic chaos harness that exercises all of this lives in
 `repro.serving.faults`; `tests/test_sweep_faults.py` is the suite.

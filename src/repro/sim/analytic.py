@@ -49,11 +49,11 @@ properties pinned in ``tests/test_sim_analytic.py``.  On degenerate
 straight-line, no-load, conflict-free programs every exposure term is
 structurally zero and the estimate equals the engine cycle-for-cycle.
 
-Trust is established by the differential harness
-(``benchmarks/bench_sim.py --analytic-smoke`` and the ``analytic_tier``
-section of ``BENCH_sim.json``): Spearman rank correlation and per-point
-relative error vs the engine over the tracked sweep, with hard pass/fail
-verdicts.  See docs/analytical.md.
+Trust is established by the differential tests in
+``tests/test_sim_analytic.py``: Spearman rank correlation, Pareto-frontier
+recall and throughput vs the engine over the tracked sweep, and the
+hybrid tier's confirmations over the screening grid.  See
+docs/analytical.md.
 """
 from __future__ import annotations
 
@@ -182,9 +182,8 @@ class Calibration:
 # Fitted on the tracked sweep domain (sweep_jobs(): 14 synthetic workloads x
 # 7 designs + baseline x table2 configs 6-7) via `fit_calibration` against
 # the event engine; baked in so the fast tier needs no calibration file to
-# hit its accuracy gates.  Re-fit per host with
-# `python -m benchmarks.bench_sim --fit-calibration` when the constants
-# drift (the differential smoke will tell you).
+# hit its accuracy gates.  Re-fit with `python -m benchmarks.run calibrate`
+# when the constants drift (the differential tests will tell you).
 DEFAULT_CALIBRATION = Calibration(
     theta_pf=0.993022, theta_mem=0.0394, theta_dep=0.0, theta_bank=0.0,
     source="builtin", n_samples=196)

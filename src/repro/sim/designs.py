@@ -122,8 +122,8 @@ def max_tolerable_latency(
     """§7.2 metric: largest MRF latency multiplier with <= ``loss`` IPC drop
     relative to the same design at 1x (main RF size held constant).
 
-    ``sim`` lets callers swap in a memoizing runner (benchmarks.orchestrator)
-    without changing the metric."""
+    ``sim`` lets callers swap in a memoizing runner
+    (`repro.serving.sweep.SimRunner.sim`) without changing the metric."""
     ref = sim(workload, design_config(design, mrf_latency_mult=1.0,
                                       rf_size_kb=BASE_RF_KB,
                                       num_warps=num_warps)).ipc

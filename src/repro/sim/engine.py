@@ -55,7 +55,7 @@ from repro.workloads.suite import Workload
 DESIGNS = ("BL", "RFC", "SHRF", "LTRF", "LTRF_conf", "LTRF_plus", "Ideal")
 
 # Bump whenever SimResult counters intentionally change: it keys the on-disk
-# sim cache (benchmarks.orchestrator), so stale artifacts never replay across
+# sim cache (repro.serving.sweep), so stale artifacts never replay across
 # engine-behavior revisions.
 # rev 2: bank_model/renumber config axes + bank-conflict counters.
 # rev 3: interval_strategy config axis + prefetch_stall_cycles counter.

@@ -124,7 +124,7 @@ def per_sm_configs(cfg: SimConfig, warps_per_cta: int = 4) -> list[SimConfig]:
 def gpu_jobs(workload: str, cfg: SimConfig,
              warps_per_cta: int = 4) -> list[tuple[str, SimConfig]]:
     """The per-SM (workload, config) jobs one GPU simulation expands into —
-    hand these to `benchmarks.orchestrator.SimRunner.prefill` to run a
+    hand these to `repro.serving.sweep.SimRunner.prefill` to run a
     GPU-scale sweep across the process pool with cache reuse."""
     return [(workload, c) for c in per_sm_configs(cfg, warps_per_cta)]
 
@@ -208,8 +208,8 @@ def simulate_gpu(workload: Workload, cfg: SimConfig,
     """Simulate a whole GPU: dispatch CTAs, run every SM, aggregate.
 
     ``sim`` accepts any ``(workload, SimConfig) -> SimResult`` callable, so
-    callers can swap in the memoizing orchestrator runner
-    (`benchmarks.orchestrator.SimRunner.sim`) — the per-SM jobs then hit the
+    callers can swap in the memoizing sweep runner
+    (`repro.serving.sweep.SimRunner.sim`) — the per-SM jobs then hit the
     compile cache, the in-process memo, and the on-disk sim cache.
     """
     results = [sim(workload, c) for c in per_sm_configs(cfg, warps_per_cta)]

@@ -73,7 +73,8 @@ def gpu_rf_power(res, tech: str = "hp-sram", cap_mult: int = 1,
 def power_comparison(workload, table2_config: int = 7, sim=None):
     """BL (HP-SRAM 1x) vs LTRF on the Table-2 design point's technology.
 
-    ``sim`` lets callers swap in a memoizing runner (benchmarks.orchestrator).
+    ``sim`` lets callers swap in a memoizing runner
+    (`repro.serving.sweep.SimRunner.sim`).
     """
     from .designs import baseline_config, design_config
     from .engine import simulate

@@ -151,7 +151,7 @@ def test_observability_doc_names_every_category_and_metric():
     for name in ("cycle_breakdown", "check_breakdown", "classify_stall",
                  "CycleAttributionError", "TraceSink", "trace_simulation",
                  "MetricsRegistry", "metrics_snapshot", "to_prometheus",
-                 "sweep_run_id", "SCHED_TID", "--obs-smoke", "--strict",
+                 "sweep_run_id", "SCHED_TID", "test_obs.py", "--strict",
                  "chrome://tracing", "fig21_breakdown"):
         assert name in doc, f"{name} undocumented in observability.md"
     # the configuration reference must cover the new knob and counter too
@@ -178,8 +178,7 @@ def test_analytical_doc_names_the_model_surface():
                  "ANALYTIC_REV", "CALIB_REV", "ANALYTIC_PASS_SCHEMA",
                  "check_pass_stats", "pass_stats", "CompiledPlan",
                  "screening_jobs", "analytic_calib", "est_mrf_accesses",
-                 "--fit-calibration", "--analytic-smoke",
-                 "BENCH_analytic_smoke.json", "analytic_tier",
+                 "calibrate", "analytic_tier",
                  "scheduler_idle"):
         assert name in doc, f"{name} undocumented in analytical.md"
     # the trust gates are stated in the doc with their pinned thresholds
@@ -215,7 +214,7 @@ def test_serving_doc_names_every_sweep_knob():
             f"FailureRecord field {f.name!r} undocumented in serving.md"
     for name in ("REPRO_FAULT_PLAN", "REPRO_SIMCACHE", "REPRO_SIM_PROCS",
                  "quarantine", "sim_key", "SweepReport", "max_cycles",
-                 "SimBudgetExceeded", "--chaos-smoke"):
+                 "SimBudgetExceeded", "test_chaos_acceptance_sweep"):
         assert name in doc, f"{name} undocumented in serving.md"
 
 

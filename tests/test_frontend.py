@@ -215,7 +215,7 @@ def test_sweep_jobs_suite_selector():
 
 
 def test_orchestrator_runs_traced_jobs():
-    from benchmarks.orchestrator import SimRunner
+    from repro.serving.sweep import SimRunner
 
     runner = SimRunner(processes=1, disk_cache=False)
     cfg = design_config("LTRF", table2_config=7, num_warps=4)

@@ -1,6 +1,8 @@
 """Unit tests for the observability layer (`repro.obs`) and its wiring:
 cycle-attribution helpers, the Chrome-trace sink, the metrics registry,
-deterministic sweep run ids, and the `benchmarks.profile` CLI.
+deterministic sweep run ids, the `benchmarks.profile` CLI, and the
+suite-level attribution verdict (the LTRF designs shrink BL's exposed
+memory stalls).
 
 The simulation-level invariants (breakdown sums to cycles on random
 programs, tracer bit-identity, GPU aggregation) live in
@@ -218,8 +220,7 @@ def test_sweep_run_id_deterministic_and_order_insensitive():
 
 
 def test_runner_metrics_and_run_id(tmp_path):
-    from benchmarks.orchestrator import SimRunner
-    from repro.serving.sweep import sweep_run_id
+    from repro.serving.sweep import SimRunner, sweep_run_id
 
     jobs = _jobs()
     runner = SimRunner(processes=1, disk_cache=False)
@@ -237,6 +238,8 @@ def test_runner_metrics_and_run_id(tmp_path):
     assert snap2["sweep_cache_hits_total"] >= len(jobs)
     for name in SWEEP_METRICS:
         assert name in snap2, name
+    prom = runner.metrics.to_prometheus()
+    assert "sweep_jobs_total" in prom and "sweep_job_latency_s_count" in prom
 
 
 def test_failure_records_carry_run_id(tmp_path):
@@ -265,6 +268,10 @@ def test_profile_cli_json_and_trace(tmp_path, capsys):
     assert out["trace_events"] > 0
     doc = json.loads(out_trace.read_text())
     assert doc["traceEvents"]
+    warp_tracks = {e["tid"] for e in doc["traceEvents"]
+                   if e.get("ph") == "M" and e.get("name") == "thread_name"
+                   and e["args"]["name"].startswith("warp ")}
+    assert len(warp_tracks) >= 2
 
 
 def test_profile_cli_breakdown_table(capsys):
@@ -277,3 +284,39 @@ def test_profile_cli_breakdown_table(capsys):
     for cat in CYCLE_CATEGORIES:
         assert cat in text
     assert "cycles" in text and "ipc=" in text
+
+
+# ------------------------------------------------- suite-level attribution
+
+@pytest.fixture(scope="module")
+def suite_runner():
+    from repro.serving.sweep import SimRunner
+    return SimRunner(processes=1, disk_cache=False)
+
+
+def _suite_runs(runner, design):
+    from repro.sim import design_config
+    from repro.workloads import workload_names
+
+    jobs = [(n, design_config(design, table2_config=7))
+            for n in workload_names()]
+    assert runner.prefill(jobs).ok
+    return {n: runner.sim(n, c) for n, c in jobs}
+
+
+@pytest.mark.parametrize("design", ["BL", "LTRF", "LTRF_conf"])
+def test_suite_cycle_attribution(suite_runner, design):
+    """Over the whole suite at Table-2 config #7 every run's breakdown sums
+    to its cycles, and each LTRF design turns BL's exposed memory stalls
+    into prefetch the scheduler mostly hides: aggregate ``mem_stall``
+    cycles and total cycles both strictly below BL's."""
+    runs = _suite_runs(suite_runner, design)
+    for name, r in runs.items():
+        assert sum(r.cycle_breakdown.values()) == r.cycles, name
+    if design == "BL":
+        return
+    bl = merge_breakdowns(r.cycle_breakdown
+                          for r in _suite_runs(suite_runner, "BL").values())
+    agg = merge_breakdowns(r.cycle_breakdown for r in runs.values())
+    assert agg["mem_stall"] < bl["mem_stall"]
+    assert sum(agg.values()) < sum(bl.values())

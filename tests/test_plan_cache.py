@@ -1,6 +1,4 @@
-"""Tests for the compile cache (core.plan_cache) and sweep orchestrator."""
-import sys
-
+"""Tests for the compile cache (core.plan_cache) and the sweep runner."""
 import pytest
 
 from repro.core.ir import parse_asm
@@ -8,6 +6,7 @@ from repro.core.plan_cache import (
     cache_clear, cache_stats, cached_intervals, cached_prefetch_ops,
     cached_renumber, compile_for_sim, program_fingerprint,
 )
+from repro.serving.sweep import SimRunner
 from repro.sim import SimConfig, Simulator, design_config, simulate
 from repro.workloads import WORKLOADS
 
@@ -126,43 +125,34 @@ def test_cache_clear_resets():
     assert cached_intervals(prog, 8).intervals
 
 
-# ------------------------------------------------------------- orchestrator
-
-def _orchestrator():
-    sys.path.insert(0, str(__import__("pathlib").Path(__file__).parent.parent))
-    from benchmarks import orchestrator
-    return orchestrator
-
+# ------------------------------------------------------------ sweep runner
 
 def test_runner_memo_and_disk_cache(tmp_path):
-    orch = _orchestrator()
     cfg = SimConfig(design="LTRF", num_warps=8)
-    runner = orch.SimRunner(processes=1, cache_dir=tmp_path)
+    runner = SimRunner(processes=1, cache_dir=tmp_path)
     a = runner.sim("kmeans", cfg)
     assert runner.stats["computed"] == 1
     b = runner.sim("kmeans", cfg)
     assert b is a and runner.stats["memo_hits"] == 1
     # a fresh runner sharing the cache dir replays from disk, exactly
-    runner2 = orch.SimRunner(processes=1, cache_dir=tmp_path)
+    runner2 = SimRunner(processes=1, cache_dir=tmp_path)
     c = runner2.sim("kmeans", cfg)
     assert runner2.stats["disk_hits"] == 1 and runner2.stats["computed"] == 0
     assert c == simulate(WORKLOADS["kmeans"], cfg)
 
 
 def test_runner_prefill_dedupes(tmp_path):
-    orch = _orchestrator()
     cfg = SimConfig(design="BL", num_warps=8)
-    runner = orch.SimRunner(processes=1, cache_dir=tmp_path)
+    runner = SimRunner(processes=1, cache_dir=tmp_path)
     runner.prefill([("bfs", cfg)] * 5 + [("nw", cfg)])
     assert runner.stats["computed"] == 2
     assert runner.sim("bfs", cfg) == simulate(WORKLOADS["bfs"], cfg)
 
 
 def test_runner_parallel_prefill_matches_serial(tmp_path):
-    orch = _orchestrator()
     jobs = [(n, SimConfig(design=d, num_warps=8))
             for n in ("kmeans", "btree") for d in ("BL", "LTRF")]
-    par = orch.SimRunner(processes=2, cache_dir=tmp_path / "p")
+    par = SimRunner(processes=2, cache_dir=tmp_path / "p")
     par.prefill(jobs)
     for name, cfg in jobs:
         assert par.sim(name, cfg) == simulate(WORKLOADS[name], cfg)

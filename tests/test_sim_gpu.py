@@ -157,10 +157,10 @@ def test_engine_rejects_gpu_scale_configs():
         simulate(W, SimConfig(design="BL", scheduler="greedy"))
 
 
-# ----------------------------------------------------------- orchestrator
+# ----------------------------------------------------------- sweep runner
 
 def test_orchestrator_gpu_path(tmp_path):
-    from benchmarks.orchestrator import SimRunner
+    from repro.serving.sweep import SimRunner
     cfg = design_config("LTRF", table2_config=7, num_warps=32, num_sms=4,
                         scheduler="lrr")
     runner = SimRunner(processes=1, cache_dir=tmp_path)

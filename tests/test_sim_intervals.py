@@ -11,8 +11,7 @@ Three layers, mirroring the bank-arbitration suite:
   with an oversized ``interval_cap``, the ``capacity`` strategy yields
   strictly fewer aggregate prefetch-stall cycles than ``paper`` on the
   paper's full compile pipeline (LTRF_conf) with no per-workload IPC
-  regression — the claims the `interval_sweep` section of BENCH_sim.json
-  records.
+  regression.
 """
 from dataclasses import replace
 
@@ -155,8 +154,7 @@ def test_capacity_never_worse_per_workload(name):
 @pytest.mark.slow
 def test_capacity_strictly_fewer_stall_cycles_in_aggregate():
     """ISSUE-5 acceptance: strictly fewer aggregate prefetch-stall cycles
-    across the high-register-pressure workloads — the verdict recorded in
-    BENCH_sim.json's ``interval_sweep`` section."""
+    across the high-register-pressure workloads."""
     tot_paper = tot_cap = 0
     for name in _sensitive_names():
         paper, cap = _strategy_pair(name)
@@ -182,28 +180,37 @@ def test_capacity_working_sets_respect_rfc_capacity():
 
 @pytest.mark.slow
 def test_interval_sweep_section_verdicts():
-    """The bench emitter computes the same verdicts this suite pins (on a
-    reduced workload slice so CI stays fast)."""
+    """The interval ablation's sweep grid (`interval_sweep_jobs`, on a
+    reduced workload slice), run through the sweep service, meets the
+    three verdicts this suite pins: capacity strictly fewer aggregate
+    prefetch-stall cycles than paper on LTRF_conf, no per-workload IPC
+    regression, and the strategy a no-op on BL."""
     import pathlib
     import sys
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
-    from benchmarks.bench_sim import measure_interval_sweep
-    import benchmarks.bench_sim as bs
     from benchmarks.sweep_subset import interval_sweep_jobs
+    from repro.serving.sweep import SimRunner
 
-    orig = bs.interval_sweep_jobs
-    bs.interval_sweep_jobs = lambda **kw: interval_sweep_jobs(
-        workloads=("srad", "sgemm"), designs=("BL", "LTRF", VERDICT_DESIGN))
-    try:
-        rep = bs.measure_interval_sweep(processes=1)
-    finally:
-        bs.interval_sweep_jobs = orig
-    assert rep["capacity_strictly_fewer_stall_cycles"] is True
-    assert rep["capacity_no_ipc_regression_all_workloads"] is True
-    assert rep["strategy_noop_on_uncached_designs"] is True
-    assert rep["verdict_design"] == VERDICT_DESIGN
-    assert {r["strategy"] for r in rep["results"]} == \
+    jobs = interval_sweep_jobs(workloads=("srad", "sgemm"),
+                               designs=("BL", "LTRF", VERDICT_DESIGN))
+    assert {c.interval_strategy for _, c in jobs} == \
         {"paper", "capacity", "fixed:8"}
+    assert all(c.interval_cap == SWEEP_CAP for _, c in jobs)
+    runner = SimRunner(processes=1, disk_cache=False)
+    assert runner.prefill(jobs).ok
+    res = {(n, c.design, c.interval_strategy): runner.sim(n, c)
+           for n, c in jobs}
+    names = ("srad", "sgemm")
+    paper = [res[n, VERDICT_DESIGN, "paper"] for n in names]
+    capacity = [res[n, VERDICT_DESIGN, "capacity"] for n in names]
+    assert sum(r.prefetch_stall_cycles for r in capacity) < \
+        sum(r.prefetch_stall_cycles for r in paper)
+    assert all(c.ipc >= p.ipc for p, c in zip(paper, capacity))
+    for n in names:
+        assert len({(r.ipc, r.prefetch_ops, r.prefetch_stall_cycles,
+                     r.mrf_accesses)
+                    for (w, d, _), r in res.items()
+                    if w == n and d == "BL"}) == 1, n
 
 
 # ----------------------------------------------------------------- GPU scale
